@@ -25,70 +25,6 @@ import os as _os
 
 import jax as _jax
 
-# Compatibility with jax 0.4.x: the distributed layer targets the
-# public ``jax.shard_map`` / ``jax.lax.axis_size`` surface (promoted
-# from jax.experimental in later releases). On older jax the same
-# implementations exist under their pre-promotion names — alias them
-# so every shard_map program (and the tests/benches driving them) runs
-# on either version. No behavioural difference: these are the same
-# functions upstream later re-exported. CYLON_TPU_NO_JAX_COMPAT=1
-# disables the aliasing (diagnostic: reproduces the bare-jax surface).
-if not _os.environ.get("CYLON_TPU_NO_JAX_COMPAT") \
-        and not hasattr(_jax, "shard_map"):  # pragma: no cover
-    import functools as _functools
-
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    @_functools.wraps(_exp_shard_map)
-    def _shard_map(f, *args, **kwargs):
-        # the pre-promotion replication checker is missing rules the
-        # promoted one has (e.g. scan carries under psum — it asks for
-        # check_rep=False itself); defaulting it off matches the
-        # promoted API's behaviour for every program in this package
-        kwargs.setdefault("check_rep", False)
-        return _exp_shard_map(f, *args, **kwargs)
-
-    _jax.shard_map = _shard_map
-
-    # axis_index over a TUPLE of axes (the hierarchical mesh's global
-    # rank) predates this jax: compose the slice-major linear index
-    # from the per-axis indices, exactly the promoted semantics
-    _axis_index0 = _jax.lax.axis_index
-
-    def _axis_index(axis_name):
-        if isinstance(axis_name, (tuple, list)):
-            from jax.core import axis_frame
-
-            idx = None
-            for a in axis_name:
-                i = _axis_index0(a)
-                idx = i if idx is None else idx * axis_frame(a) + i
-            return idx
-        return _axis_index0(axis_name)
-
-    _jax.lax.axis_index = _axis_index
-if not _os.environ.get("CYLON_TPU_NO_JAX_COMPAT") \
-        and not hasattr(_jax, "enable_x64"):  # pragma: no cover
-    from jax.experimental import enable_x64 as _enable_x64
-
-    _jax.enable_x64 = _enable_x64
-if not _os.environ.get("CYLON_TPU_NO_JAX_COMPAT") \
-        and not hasattr(_jax.lax, "axis_size"):  # pragma: no cover
-    def _axis_size(axis_name):
-        """Static size of a mapped mesh axis (jax.lax.axis_size
-        backport: ``jax.core.axis_frame`` IS the size lookup on the
-        trace context's axis env pre-promotion)."""
-        from jax.core import axis_frame
-
-        if isinstance(axis_name, (tuple, list)):
-            size = 1
-            for a in axis_name:
-                size *= axis_frame(a)
-            return size
-        return axis_frame(axis_name)
-
-    _jax.lax.axis_size = _axis_size
-
 # Tabular data is int64/float64-shaped (reference benchmarks and the whole
 # pycylon surface assume 64-bit keys); without x64 JAX silently downcasts.
 # Opt out with CYLON_TPU_NO_X64=1 for bf16/int32-only pipelines.
@@ -98,20 +34,15 @@ if not _os.environ.get("CYLON_TPU_NO_X64"):
 # Persistent XLA compilation cache: relational programs are large (a
 # distributed join is one fused shard_map program) and TPU compiles are
 # minutes cold — but byte-identical across processes, so cache them on
-# disk. CYLON_TPU_CACHE_DIR overrides the location; CYLON_TPU_NO_CACHE=1
-# disables (parity note: the reference has no analog — XLA-specific).
-if not _os.environ.get("CYLON_TPU_NO_CACHE"):
-    _cache_dir = _os.environ.get(
-        "CYLON_TPU_CACHE_DIR",
-        _os.path.join(_os.path.expanduser("~"), ".cache", "cylon_tpu",
-                      "xla"))
-    try:
-        _os.makedirs(_cache_dir, exist_ok=True)
-        _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                           1.0)
-    except (OSError, AttributeError):  # read-only fs / very old jax
-        pass
+# disk. JAX_COMPILATION_CACHE_DIR (read by JAX itself) places the cache
+# from outside; otherwise it lives at a FIXED path in the checkout —
+# the path is part of the cache key, so it must not move between runs.
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))
+_jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 from cylon_tpu.utils.logging import init_logging as _init_logging
 

@@ -181,9 +181,9 @@ def _groupby_compiled(table: Table, *, by, aggs, out_cap,
     # WIDE value sets instead ride one packed row gather through the
     # sorted index — each sort payload re-moves its bytes through every
     # merge stage (see selection.PAYLOAD_SORT_MAX_WORDS)
-    from cylon_tpu.ops.selection import payload_words, use_gather_path
-
-    wide = use_gather_path(payload_words(src_cols), cap)
+    # (on the TPU the group sort carries at most one 64-bit column:
+    # TPU_GROUP_SORT_MAX_WORDS, at the end of this module)
+    wide = _gather_values(src_cols, cap)
     if wide:
         payloads, pack = [iota], None
     else:
@@ -560,3 +560,23 @@ def _quantile(c: Column, gid_v, gvalid, out_cap: int, q: float) -> Column:
     take_hi = jnp.clip(start + hi, 0, max(cap - 1, 0))
     data = v_s[take_lo] * (1 - w) + v_s[take_hi] * w
     return Column(data, gvalid & (n > 0), dtypes.from_numpy_dtype(f))
+
+
+#: value words the TPU group sort may carry: one 64-bit column. Each
+#: payload word adds compile time to the sort (described v5e, PR 21: a
+#: (u8, u64, i32) sort of 2M rows compiles in 58 s, 124 s with two more
+#: f64 columns); the 10M-rows/chip distributed groupby of
+#: ``chip_smoke.py --chips 4``, whose two group sorts carried two f64
+#: columns, compiled in 349 s, and in 102 s with them gathered.
+TPU_GROUP_SORT_MAX_WORDS = 2
+
+
+def _gather_values(src_cols, cap: int) -> bool:
+    """Gather the value columns through the sorted row index instead of
+    carrying them through the group sort?"""
+    from cylon_tpu.ops.selection import payload_words, use_gather_path
+    from cylon_tpu.platform import current_platform
+
+    words = payload_words(src_cols)
+    return use_gather_path(words, cap) or (
+        words > TPU_GROUP_SORT_MAX_WORDS and current_platform() == "tpu")

@@ -123,7 +123,7 @@ def _probe_jnp(pbids: jax.Array, pwords, table: jax.Array, bwords):
 
 
 def _build(bids, nb: int, width: int):
-    if pk.bucket_join_ok(bids, nb, width, 0, 0):
+    if pk.bucket_join_ok(bids, nb, width, 0):
         return pk.bucket_build(bids, nb, width)
     return _build_jnp(bids, nb, width)
 
@@ -132,7 +132,7 @@ def _probe(pbids, pwords, table, bwords):
     nb = table.shape[1]
     width = table.shape[0]
     bcap = bwords[0].shape[0] if bwords else 0
-    if bcap and pk.bucket_join_ok(pbids, nb, width, len(bwords), bcap):
+    if bcap and pk.bucket_join_ok(pbids, nb, width, len(bwords)):
         return pk.bucket_probe(pbids, pwords, table, bwords)
     return _probe_jnp(pbids, pwords, table, bwords)
 

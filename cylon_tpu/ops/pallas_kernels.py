@@ -21,7 +21,7 @@ small and large group counts. See ``ops/groupby.py:_segment_sum``.)
 All kernels run in ``interpret`` mode off-TPU, so the exact code path
 unit-tested on the CPU mesh (``tests/conftest.py``) is what compiles on
 real chips. Dispatch policy: :func:`enabled` — auto-on for the TPU
-backend, forceable via ``CYLON_PALLAS=1|0|interpret``.
+backend, forceable via ``CYLON_PALLAS=1|0``.
 """
 
 import functools
@@ -51,14 +51,16 @@ def enabled() -> bool:
     m = _mode()
     if m in ("0", "off", "false"):
         return False
-    if m in ("1", "on", "true", "interpret"):
+    if m in ("1", "on", "true"):
         return True
     return current_platform() == "tpu"
 
 
 def _interpret() -> bool:
-    """Interpret off-TPU so CPU tests execute the same kernels."""
-    return _mode() == "interpret" or current_platform() != "tpu"
+    """Interpret off-TPU so CPU tests (``CYLON_PALLAS=1``) execute the
+    same kernels. On the TPU a kernel always compiles: interpret mode
+    is decided by the platform alone, never by an option."""
+    return current_platform() != "tpu"
 
 
 def _vma_varying(x) -> bool:
@@ -344,6 +346,11 @@ def pair_max_scan(hi: jax.Array, lo: jax.Array):
 # tile with the table pinned in VMEM, trading vector throughput for a
 # single pass over HBM (the jnp twins in ``ops/hash_join.py`` pay
 # ~width scatter/gather passes instead; both are bit-identical).
+#
+# Mosaic loads and stores VMEM only as vectors, and scalars only from
+# SMEM: the per-row bucket ids, probe words and match masks travel in
+# SMEM tiles, and each row touches its bucket through the 128-lane
+# [width, 128] table slice holding it, selected by a lane mask.
 
 _JOIN_LANES = 128      # lanes per build/probe tile (8 x 128 elements)
 
@@ -357,56 +364,57 @@ def _32bit_trace(interpret: bool):
         else jax.enable_x64(False)
 
 
+def _bucket_slice(b, width: int):
+    """(lane-aligned slice, [width, 128] lane mask) of bucket ``b``.
+    ``lax.div``/``lax.rem``, not ``//``/``%``: the Python operators
+    lower through ``sign``, which Mosaic re-traces at lowering time
+    under the package-wide x64 and then refuses as 64-bit."""
+    lanes = np.int32(_JOIN_LANES)
+    off = pl.multiple_of(jax.lax.div(b, lanes) * lanes, _JOIN_LANES)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (width, _JOIN_LANES), 1)
+    return pl.ds(off, _JOIN_LANES), lane == jax.lax.rem(b, lanes)
+
+
 def _bucket_build_kernel(width: int, rows: int, lanes: int,
-                         bid_ref, table_ref, ovf_ref):
+                         bid_ref, table_ref):
     """Sequential first-free-entry insertion, ascending row order.
 
-    ``bid_ref``: [rows, lanes] int32 bucket ids (-1 = skip: padding or
-    invalid row). ``table_ref``: [width, nb] int32 bucket table —
-    entry e of bucket b ends up holding the (e+1)-th inserted row id
-    (ascending), -1 when empty; the ENTRY-major layout keeps the lane
-    dimension at nb (pow-2, lane-aligned), not the tiny chain width.
-    ``ovf_ref``: [1, 1] SMEM count of rows whose chain was full — any
-    nonzero means the caller must take the sort fallback (the table is
-    then missing rows and MUST not be probed for real results).
+    ``bid_ref``: [rows, lanes] int32 SMEM bucket ids (-1 = skip:
+    padding or invalid row). ``table_ref``: [width, nb] int32 bucket
+    table — entry e of bucket b ends up holding the (e+1)-th inserted
+    row id (ascending), -1 when empty; the ENTRY-major layout keeps the
+    lane dimension at nb (pow-2, lane-aligned), not the tiny chain
+    width. A row whose chain is full is dropped (the caller counts
+    them: valid rows minus stored entries).
     """
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         table_ref[...] = jnp.full_like(table_ref, -1)
-        ovf_ref[0, 0] = jnp.int32(0)
 
-    base = (i * rows * lanes).astype(jnp.int32)
+    base = i.astype(jnp.int32) * np.int32(rows * lanes)
+    entry = jax.lax.broadcasted_iota(jnp.int32, (width, lanes), 0)
+    nl = np.int32(lanes)
 
     def row_body(k, carry):
-        b = bid_ref[k // lanes, k % lanes]
+        k = k.astype(jnp.int32)      # interpret mode: ambient x64 index
+        b = bid_ref[jax.lax.div(k, nl), jax.lax.rem(k, nl)]
 
         @pl.when(b >= 0)
         def _insert():
-            def entry(e, placed):
-                cur = table_ref[e, b]
-                take = jnp.logical_and(jnp.logical_not(placed), cur < 0)
-
-                @pl.when(take)
-                def _write():
-                    # explicit i32: the interpret-mode state discharge
-                    # re-evaluates stores under the AMBIENT x64 setting,
-                    # where a weakly-typed sum would widen and mismatch
-                    # the i32 table
-                    table_ref[e, b] = (base + k).astype(jnp.int32)
-
-                return jnp.logical_or(placed, take)
-
-            placed = jax.lax.fori_loop(0, width, entry, jnp.bool_(False))
-
-            @pl.when(jnp.logical_not(placed))
-            def _overflow():
-                ovf_ref[0, 0] = (ovf_ref[0, 0] + 1).astype(jnp.int32)
+            cols, hit = _bucket_slice(b, width)
+            cur = table_ref[:, cols]
+            # first free entry of the bucket's column (width = none)
+            first = jnp.min(jnp.where(hit & (cur < 0), entry,
+                                      np.int32(width)),
+                            axis=0, keepdims=True)
+            table_ref[:, cols] = jnp.where(hit & (entry == first),
+                                           base + k, cur)
 
         return carry
 
-    jax.lax.fori_loop(0, rows * lanes, row_body, jnp.int32(0))
+    jax.lax.fori_loop(0, rows * lanes, row_body, np.int32(0))
 
 
 @functools.partial(jax.jit, static_argnames=("nb", "width", "interpret"))
@@ -416,23 +424,25 @@ def _bucket_build_impl(bids, nb: int, width: int, interpret: bool):
     tile = r * b
     capp = max(-(-cap // tile) * tile, tile)
     bids2 = _pad_to(bids, capp, -1).reshape(capp // b, b)
+    nbp = max(nb, b)              # whole 128-lane slices per bucket
     # Mosaic rejects the i64 constants x64 puts into BlockSpec index
     # maps — trace 32-bit for the real-TPU compile. The interpret-mode
     # evaluator is the opposite: its state discharge re-evaluates
     # stores under the AMBIENT x64 setting, so an x64-off trace there
     # manufactures i32/i64 mixes inside the loop bodies.
     with _32bit_trace(interpret):
-        table, ovf = pl.pallas_call(
+        table = pl.pallas_call(
             functools.partial(_bucket_build_kernel, width, r, b),
             grid=(capp // tile,),
-            in_specs=[pl.BlockSpec((r, b), lambda i: (i, 0))],
-            out_specs=[pl.BlockSpec((width, nb), lambda i: (0, 0)),
-                       pl.BlockSpec(memory_space=pltpu.SMEM)],
-            out_shape=[_out_struct((width, nb), jnp.int32, bids2),
-                       _out_struct((1, 1), jnp.int32, bids2)],
+            in_specs=[pl.BlockSpec((r, b), lambda i: (i, 0),
+                                   memory_space=pltpu.SMEM)],
+            out_specs=pl.BlockSpec((width, nbp), lambda i: (0, 0)),
+            out_shape=_out_struct((width, nbp), jnp.int32, bids2),
             interpret=interpret,
-        )(bids2)
-    return table, ovf[0, 0]
+        )(bids2)[:, :nb]
+    ovf = (jnp.sum(bids >= 0, dtype=jnp.int32)
+           - jnp.sum(table >= 0, dtype=jnp.int32))
+    return table, ovf
 
 
 def bucket_build(bids: jax.Array, nb: int, width: int):
@@ -445,38 +455,47 @@ def bucket_build(bids: jax.Array, nb: int, width: int):
 def _bucket_probe_kernel(width: int, nwords: int, lanes: int, *refs):
     """Per-element bucket lookup + exact key compare.
 
-    refs: pbid [rows, lanes] i32 (-1 = invalid probe row), then
-    ``nwords`` probe word tiles [rows, lanes] u32, the full
-    [width, nb] table, the full [nwords, bcapp] build word matrix, and
-    the [rows, lanes] i32 output mask (bit e set <=> table[e, bucket]
-    holds a row whose canonical key words all equal the probe row's).
+    refs: pbid [rows, lanes] SMEM i32 (-1 = invalid probe row), then
+    ``nwords`` SMEM probe word tiles [rows, lanes] i32, the full
+    [width, nb] table, ``nwords`` [width, nb] tables of the build key
+    words each entry holds, and the [rows, lanes] SMEM i32 output mask
+    (bit e set <=> table[e, bucket] holds a row whose canonical key
+    words all equal the probe row's).
     """
     pbid_ref = refs[0]
     pword_refs = refs[1:1 + nwords]
     table_ref = refs[1 + nwords]
-    bwords_ref = refs[2 + nwords]
+    tword_refs = refs[2 + nwords:2 + 2 * nwords]
     mask_ref = refs[-1]
     rows = pbid_ref.shape[0]
+    bit = jnp.left_shift(
+        np.int32(1), jax.lax.broadcasted_iota(jnp.int32, (width, lanes), 0))
+
+    nl = np.int32(lanes)
 
     def body(k, carry):
-        r = k // lanes
-        c = k % lanes
+        k = k.astype(jnp.int32)      # interpret mode: ambient x64 index
+        r = jax.lax.div(k, nl)
+        c = jax.lax.rem(k, nl)
         b = pbid_ref[r, c]
-        bsafe = jnp.maximum(b, 0)
-        m = jnp.int32(0)
-        for e in range(width):
-            rr = table_ref[e, bsafe]
-            rsafe = jnp.maximum(rr, 0)
-            eq = rr >= 0
-            for w in range(nwords):
-                eq = jnp.logical_and(
-                    eq, pword_refs[w][r, c] == bwords_ref[w, rsafe])
-            m = m | jnp.where(eq, jnp.int32(1 << e), jnp.int32(0))
-        mask_ref[r, c] = jnp.where(b >= 0, m, jnp.int32(0)
-                                   ).astype(jnp.int32)
+        cols, hit = _bucket_slice(jnp.maximum(b, 0), width)
+        eq = hit & (table_ref[:, cols] >= 0)
+        for w in range(nwords):
+            eq = eq & (tword_refs[w][:, cols] == pword_refs[w][r, c])
+        # entries' bits are disjoint and only the bucket's lane is hit:
+        # sum over entries, then a max (the scalar reduction Mosaic
+        # lowers without widening, unlike a sum) picks that lane
+        m = jnp.max(jnp.sum(jnp.where(eq, bit, np.int32(0)), axis=0,
+                            keepdims=True, dtype=jnp.int32))
+        mask_ref[r, c] = jnp.where(b >= 0, m, np.int32(0))
         return carry
 
-    jax.lax.fori_loop(0, rows * lanes, body, jnp.int32(0))
+    jax.lax.fori_loop(0, rows * lanes, body, np.int32(0))
+
+
+def _as_i32(x):
+    return jax.lax.bitcast_convert_type(x, jnp.int32) \
+        if x.dtype == jnp.uint32 else x.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("width", "interpret"))
@@ -489,22 +508,26 @@ def _bucket_probe_impl(pbids, pwords, table, bwords, width: int,
     tile = r * b
     capp = max(-(-cap // tile) * tile, tile)
     pbids2 = _pad_to(pbids, capp, -1).reshape(capp // b, b)
-    pwords2 = [_pad_to(w, capp, 0).reshape(capp // b, b) for w in pwords]
-    bcap = bwords[0].shape[0]
-    bcapp = max(-(-bcap // b) * b, b)
-    bw = jnp.stack([_pad_to(w, bcapp, 0) for w in bwords])
+    pwords2 = [_pad_to(_as_i32(w), capp, 0).reshape(capp // b, b)
+               for w in pwords]
+    # the build words each table entry holds, gathered once in XLA:
+    # the kernel then compares whole [width, 128] slices, never a
+    # data-dependent scalar gather
+    nbp = max(nb, b)              # whole 128-lane slices per bucket
+    table = jnp.pad(table, ((0, 0), (0, nbp - nb)), constant_values=-1)
+    rsafe = jnp.clip(table, 0, bwords[0].shape[0] - 1)
+    twords = [_as_i32(w)[rsafe] for w in bwords]
+    smem = pl.BlockSpec((r, b), lambda i: (i, 0), memory_space=pltpu.SMEM)
+    whole = pl.BlockSpec((width, nbp), lambda i: (0, 0))
     with _32bit_trace(interpret):
         out = pl.pallas_call(
             functools.partial(_bucket_probe_kernel, width, nwords, b),
             grid=(capp // tile,),
-            in_specs=[pl.BlockSpec((r, b), lambda i: (i, 0))]
-                     * (1 + nwords)
-                     + [pl.BlockSpec((width, nb), lambda i: (0, 0)),
-                        pl.BlockSpec((nwords, bcapp), lambda i: (0, 0))],
-            out_specs=pl.BlockSpec((r, b), lambda i: (i, 0)),
+            in_specs=[smem] * (1 + nwords) + [whole] * (1 + nwords),
+            out_specs=smem,
             out_shape=_out_struct((capp // b, b), jnp.int32, pbids2),
             interpret=interpret,
-        )(pbids2, *pwords2, table, bw)
+        )(pbids2, *pwords2, table, *twords)
     return out.reshape(capp)[:cap]
 
 
@@ -517,17 +540,16 @@ def bucket_probe(pbids: jax.Array, pwords, table: jax.Array, bwords):
                               table.shape[0], _interpret())
 
 
-#: VMEM budget for the resident bucket table + build key words — above
-#: this the Pallas path loses its "table stays on-chip" premise and the
-#: jnp twins (HBM scatters/gathers) take over.
+#: VMEM budget for the resident bucket table + its per-entry key-word
+#: tables — above this the Pallas path loses its "table stays on-chip"
+#: premise and the jnp twins (HBM scatters/gathers) take over.
 JOIN_VMEM_BUDGET = 4 << 20
 
 
-def bucket_join_ok(x, nb: int, width: int, nwords: int,
-                   build_cap: int) -> bool:
+def bucket_join_ok(x, nb: int, width: int, nwords: int) -> bool:
     """Can the Pallas bucket kernels run for this operand here? Gated
-    like every kernel on :func:`usable_for`, plus the table + build
-    words must fit the VMEM budget."""
+    like every kernel on :func:`usable_for`, plus the table and the
+    probe's ``nwords`` key-word tables must fit the VMEM budget."""
     import os as _os
 
     try:
@@ -535,5 +557,5 @@ def bucket_join_ok(x, nb: int, width: int, nwords: int,
                                      JOIN_VMEM_BUDGET))
     except ValueError:
         budget = JOIN_VMEM_BUDGET
-    resident = (nb * width + nwords * max(build_cap, _JOIN_LANES)) * 4
+    resident = (1 + nwords) * nb * width * 4
     return usable_for(x) and resident <= budget

@@ -202,10 +202,17 @@ PAYLOAD_GATHER_MIN_ROWS = 2_000_000
 def use_gather_path(total_words: int, rows: int) -> bool:
     """Shared wide-table crossover for permute/groupby/unique/
     segmented_totals: sort a permutation + packed-gather instead of
-    carrying payloads, once BOTH the width and the row count pass the
-    measured knees."""
+    carrying payloads, once the width passes its knee — and, off the
+    TPU, the row count too. On the TPU the sort's COMPILE time grows
+    faster than linearly in the 32-bit words it carries, at any row
+    count (v5e, measured by chip_smoke.py in PR 21: TPC-H Q1's 24-word
+    sort of a 375k-row groupby result took 684 s of compile), so there
+    a wide table never rides the sort."""
+    from cylon_tpu.platform import current_platform
+
     return (total_words > PAYLOAD_SORT_MAX_WORDS
-            and rows >= PAYLOAD_GATHER_MIN_ROWS)
+            and (rows >= PAYLOAD_GATHER_MIN_ROWS
+                 or current_platform() == "tpu"))
 
 
 def _column_words(c: Column) -> int:

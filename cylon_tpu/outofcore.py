@@ -132,7 +132,7 @@ def _as_chunks(src, chunk_rows: int):
     """Accept a dict of host arrays (sliced into chunks), or any
     iterable of dicts / Tables (used as-is). Every chunk passes the
     ``chunk_source`` injection point — the place a streaming source
-    (tunneled parquet reader, network stream) fails in production."""
+    (remote parquet reader, network stream) fails in production."""
     from cylon_tpu.table import Table
 
     if isinstance(src, Mapping):

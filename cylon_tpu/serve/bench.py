@@ -173,26 +173,9 @@ def _materialize(out):
 def _results_match(got, want) -> bool:
     """Order-insensitive equality between a served result and its
     single-query oracle (float columns to 1e-9 rtol)."""
-    import pandas as pd
+    from cylon_tpu.testing import compare_results
 
-    if isinstance(want, float):
-        return bool(np.isclose(float(got), want, rtol=1e-9))
-    if not isinstance(want, pd.DataFrame):
-        return bool(np.allclose(np.asarray(got), np.asarray(want)))
-    if list(got.columns) != list(want.columns) or len(got) != len(want):
-        return False
-    keys = [c for c in want.columns
-            if not np.issubdtype(want[c].dtype, np.floating)]
-    g = got.sort_values(keys or list(got.columns)).reset_index(drop=True)
-    w = want.sort_values(keys or list(want.columns)).reset_index(drop=True)
-    for c in want.columns:
-        if np.issubdtype(want[c].dtype, np.floating):
-            if not np.allclose(g[c].to_numpy(), w[c].to_numpy(),
-                               rtol=1e-9):
-                return False
-        elif list(g[c]) != list(w[c]):
-            return False
-    return True
+    return compare_results(got, want, ignore_order=True)[0] is None
 
 
 def _staged_query(cq, resident, env):

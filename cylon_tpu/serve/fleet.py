@@ -266,6 +266,12 @@ def encode_value(v) -> dict:
             f"fleet result codec cannot encode {type(x).__name__}")
 
     def _enc_col(arr):
+        if pd is not None and isinstance(arr, pd.Series):
+            if isinstance(arr.dtype, pd.StringDtype):
+                # a missing string is None on the wire, whatever NA
+                # marker the pandas string dtype uses
+                arr = arr.astype(object).where(arr.notna(), None)
+            arr = arr.to_numpy()
         arr = np.asarray(arr)
         if np.issubdtype(arr.dtype, np.datetime64):
             return {"dtype": str(arr.dtype), "kind": "datetime",
@@ -283,7 +289,7 @@ def encode_value(v) -> dict:
 
     if pd is not None and isinstance(v, pd.DataFrame):
         return {"__fleet__": "frame", "columns": list(map(str, v.columns)),
-                "cols": {str(c): _enc_col(v[c].to_numpy())
+                "cols": {str(c): _enc_col(v[c])
                          for c in v.columns}}
     if isinstance(v, np.ndarray):
         return {"__fleet__": "ndarray", "col": _enc_col(v)}
@@ -316,8 +322,9 @@ def decode_value(env: "dict | None"):
         if c["kind"] == "num":
             data = [_dec_item(x) for x in c["data"]]
             return np.asarray(data, dtype=np.dtype(c["dtype"]))
-        return np.asarray([_dec_item(x) for x in c["data"]],
-                          dtype=object)
+        # a Series keeps object dtype: pandas would otherwise infer its
+        # string dtype and turn a missing string (None) into NaN
+        return pd.Series([_dec_item(x) for x in c["data"]], dtype=object)
 
     kind = env.get("__fleet__")
     if kind == "frame":
@@ -325,7 +332,7 @@ def decode_value(env: "dict | None"):
                              for c in env["columns"]},
                             columns=env["columns"])
     if kind == "ndarray":
-        return _dec_col(env["col"])
+        return np.asarray(_dec_col(env["col"]))
     if kind == "scalar":
         return _dec_item(env.get("data"))
     raise InvalidArgument(f"unknown fleet value envelope {kind!r}")
@@ -1679,6 +1686,46 @@ class EngineProc:
             return self.proc.wait(10)
 
 
+#: chip index -> the live engine process pinned to it (this parent's)
+_PINNED: dict = {}
+
+
+def _host_chips() -> int:
+    """TPU chips on this host, counted from their device nodes: the
+    router must not touch JAX, since a process that has holds the chip
+    its engines need."""
+    import glob
+
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def _pin_chip(child_env: dict) -> "int | None":
+    """Pin an engine child to the lowest chip no live engine holds (its
+    env gains the one-chip-per-process libtpu bounds); None on a host
+    without chips or a child configured off the TPU. More engines than
+    chips is an error."""
+    platforms = child_env.get("JAX_PLATFORMS", "")
+    chips = _host_chips()
+    if not chips or (platforms and "tpu" not in platforms.split(",")):
+        return None
+    free = [i for i in range(chips)
+            if i not in _PINNED or _PINNED[i].poll() is not None]
+    if not free:
+        raise InvalidArgument(
+            f"{chips} chip(s) on this host, each held by a live engine: "
+            f"one engine per chip")
+    i = free[0]
+    port = 8476 + i
+    child_env.update({
+        "TPU_VISIBLE_CHIPS": str(i),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{port}",
+        "TPU_MESH_CONTROLLER_PORT": str(port)})
+    return i
+
+
 def spawn_engine(root: str, name: str, sf: float = 0.002,
                  seed: int = 0, mix=DEFAULT_MIX,
                  env_extra: "dict | None" = None,
@@ -1686,7 +1733,8 @@ def spawn_engine(root: str, name: str, sf: float = 0.002,
     """Spawn ``python -m cylon_tpu.serve.fleet`` as one engine process
     under ``root`` and wait for its READY line. The child's stderr
     streams to ``<root>/<name>.log`` (post-mortem evidence); stdout is
-    drained by a daemon thread after the handshake."""
+    drained by a daemon thread after the handshake. On a chip host
+    each engine is pinned to a chip of its own (:func:`_pin_chip`)."""
     os.makedirs(root, exist_ok=True)
     log_path = os.path.join(root, f"{name}.log")
     cmd = [sys.executable, "-m", "cylon_tpu.serve.fleet",
@@ -1704,10 +1752,13 @@ def spawn_engine(root: str, name: str, sf: float = 0.002,
     child_env.pop("CHAOS_KILL", None)
     child_env.pop("CHAOS_OOM", None)
     child_env.update(env_extra or {})
+    chip = _pin_chip(child_env)
     logf = open(log_path, "ab")
     proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, stderr=logf,
                             env=child_env, text=True)
     logf.close()  # the child holds its own descriptor now
+    if chip is not None:
+        _PINNED[chip] = proc
 
     # the handshake read rides a daemon reader thread so ready_timeout
     # is ENFORCED — a child wedged before printing READY (stuck

@@ -40,9 +40,7 @@ from cylon_tpu.telemetry import events, memory, profile, timeseries, trace
 from cylon_tpu.telemetry.aggregate import (gather_metrics,
                                            gather_traces,
                                            merge_snapshots)
-from cylon_tpu.telemetry.export import (HBM_PEAK_BYTES_PER_SEC,
-                                        ICI_LINK_BYTES_PER_SEC,
-                                        REQUIRED_BENCH_KEYS,
+from cylon_tpu.telemetry.export import (PEAKS, REQUIRED_BENCH_KEYS,
                                         bench_metrics,
                                         chrome_trace_json,
                                         fraction_of_peak,
@@ -68,8 +66,7 @@ __all__ = [
     "total", "add_record", "get_records", "merge_snapshots",
     "gather_metrics", "gather_traces", "json_safe", "snapshot_to_json",
     "to_prometheus", "metrics_dir", "write_snapshot", "bench_metrics",
-    "REQUIRED_BENCH_KEYS", "HBM_PEAK_BYTES_PER_SEC",
-    "ICI_LINK_BYTES_PER_SEC", "fraction_of_peak", "trace",
+    "REQUIRED_BENCH_KEYS", "PEAKS", "fraction_of_peak", "trace",
     "to_chrome_trace", "chrome_trace_json", "write_chrome_trace",
     "tenant_scope", "current_tenant", "tenant_labels",
     "merge_histograms", "memory", "profile", "events", "timeseries",

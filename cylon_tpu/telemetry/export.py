@@ -23,31 +23,32 @@ import time
 __all__ = [
     "json_safe", "snapshot_to_json", "to_prometheus", "metrics_dir",
     "write_snapshot", "arm_exporters", "bench_metrics",
-    "REQUIRED_BENCH_KEYS", "HBM_PEAK_BYTES_PER_SEC",
-    "ICI_LINK_BYTES_PER_SEC", "fraction_of_peak",
+    "REQUIRED_BENCH_KEYS", "PEAKS", "fraction_of_peak",
     "to_chrome_trace", "chrome_trace_json", "write_chrome_trace",
     "SHARD_PID_BASE",
 ]
 
 # ---------------------------------------------------------------- roofline
-#: v5e per-chip HBM bandwidth (bytes/s) — the roofline every exchange
-#: bytes/s number is reported against (a shuffle that moves device rows
-#: through sort + DMA is HBM-bound before it is ICI-bound at W=1).
-HBM_PEAK_BYTES_PER_SEC = 819e9
+#: published per-chip peaks, keyed by ``device.device_kind`` (Google
+#: Cloud documentation, "TPU v5e": 819 GB/s HBM; 1,600 Gbit/s of
+#: chip-to-chip interconnect over 4 links, 50 GB/s per link). A kind
+#: missing here has no roofline: :func:`fraction_of_peak` raises.
+PEAKS = {
+    "TPU v5 lite": {"hbm_bytes_per_sec": 819e9,
+                    "ici_link_bytes_per_sec": 50e9},
+}
 
-#: v5e ICI, per link, bytes/s (400 Gb/s x 4 links per chip): the peak
-#: for the per-peer streams of a multi-chip all-to-all.
-ICI_LINK_BYTES_PER_SEC = 50e9
 
-
-def fraction_of_peak(bytes_per_sec: float,
-                     peak: float = HBM_PEAK_BYTES_PER_SEC) -> float:
-    """Measured exchange bandwidth as a fraction of a hardware peak —
-    the roofline position of a bench number. Callers label which peak
-    they divided by (HBM for single-chip/self-DMA paths, ICI per-link
-    for cross-chip streams); the division itself is kept here so every
-    bench reports it the same way."""
-    return bytes_per_sec / peak if peak > 0 else 0.0
+def fraction_of_peak(bytes_per_sec: float, device_kind: str,
+                     peak: str = "hbm_bytes_per_sec") -> float:
+    """Measured bandwidth as a fraction of ``device_kind``'s ``peak``
+    (``"hbm_bytes_per_sec"`` for single-chip/self-DMA paths,
+    ``"ici_link_bytes_per_sec"`` for cross-chip streams) — the roofline
+    position of a bench number."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r} (known: {sorted(PEAKS)})")
+    return bytes_per_sec / PEAKS[device_kind][peak]
 
 
 def json_safe(x):

@@ -372,7 +372,11 @@ def generate_pandas(sf: float = 0.01, seed: int = 0):
     """Same data as :func:`generate`, as pandas DataFrames (the
     correctness oracle side, mirroring the reference's pandas-parity
     test pattern, ``python/test/test_df_dist_sorting.py``)."""
+    return to_pandas(generate(sf, seed))
+
+
+def to_pandas(data):
+    """A :func:`generate` mapping as pandas DataFrames."""
     import pandas as pd
 
-    return {name: pd.DataFrame(cols)
-            for name, cols in generate(sf, seed).items()}
+    return {name: pd.DataFrame(cols) for name, cols in data.items()}

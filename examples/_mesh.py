@@ -1,7 +1,7 @@
 """Shared example bootstrap: a virtual 8-device CPU mesh by default (the
 reference's `mpirun --oversubscribe` analog). Set CYLON_EXAMPLES_TPU=1
-to run on real chips instead — kept opt-in because probing for TPUs
-initialises (and exclusively leases) the backend."""
+to run on real chips instead — kept opt-in because a process that
+initialises the TPU backend holds the chip until it exits."""
 
 import os
 import sys

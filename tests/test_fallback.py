@@ -23,6 +23,7 @@ import pytest
 
 from cylon_tpu import fallback, resilience, telemetry
 from cylon_tpu.errors import InvalidArgument, ResourceExhausted
+from cylon_tpu.testing import assert_results_match
 from cylon_tpu.resilience import (FaultPlan, FaultRule,
                                   KILL_EXIT_CODE)
 from cylon_tpu.telemetry import memory
@@ -50,17 +51,7 @@ def tpch_data_01():
 
 
 def _assert_matches(got, want):
-    if isinstance(want, float):
-        assert np.isclose(float(got), want, rtol=1e-9)
-        return
-    assert list(got.columns) == list(want.columns)
-    assert len(got) == len(want)
-    for c in want.columns:
-        if np.issubdtype(want[c].dtype, np.floating):
-            np.testing.assert_allclose(
-                got[c].to_numpy(), want[c].to_numpy(), rtol=1e-9)
-        else:
-            assert list(got[c]) == list(want[c])
+    assert_results_match(got, want)
 
 
 def _sorted_all(df):

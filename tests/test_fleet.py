@@ -224,6 +224,35 @@ def test_value_codec_round_trips_frames_scalars_bytes():
     assert arr.tolist() == [1.0, 2.0]
 
 
+# ------------------------------------------------- chip pinning
+def test_engines_pin_one_chip_each_and_refuse_extra(monkeypatch):
+    from cylon_tpu.errors import InvalidArgument
+    from cylon_tpu.serve import fleet
+
+    class Proc:
+        def __init__(self, alive=True):
+            self.alive = alive
+
+        def poll(self):
+            return None if self.alive else 0
+
+    monkeypatch.setattr(fleet, "_host_chips", lambda: 2)
+    monkeypatch.setattr(fleet, "_PINNED", {})
+    assert fleet._pin_chip({"JAX_PLATFORMS": "cpu"}) is None
+    envs = [{}, {}]
+    for i, env in enumerate(envs):
+        assert fleet._pin_chip(env) == i
+        fleet._PINNED[i] = Proc()
+        assert env["TPU_VISIBLE_CHIPS"] == str(i)
+        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert envs[0]["TPU_MESH_CONTROLLER_PORT"] != \
+        envs[1]["TPU_MESH_CONTROLLER_PORT"]
+    with pytest.raises(InvalidArgument, match="one engine per chip"):
+        fleet._pin_chip({})
+    fleet._PINNED[1].alive = False          # a dead engine frees its chip
+    assert fleet._pin_chip({}) == 1
+
+
 # ------------------------------------------------- affinity
 def test_affinity_order_is_stable_and_spreads():
     names = ["e0", "e1", "e2"]

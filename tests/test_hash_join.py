@@ -5,7 +5,7 @@ byte-identical for ``ordered=True`` (both restore pandas order) across
 every supported ``how`` x dtype (incl. bytescol 2-D keys) x null
 pattern x size (empty / all-duplicate) x capacities straddling the
 overflow threshold. The Pallas kernels run in interpret mode here
-(``CYLON_PALLAS=interpret``) so the exact kernel code paths are
+(``CYLON_PALLAS=1`` off the TPU) so the exact kernel code paths are
 exercised under the tier-1 gate without TPU hardware; the jnp twins
 are pinned bit-identical to them.
 """
@@ -23,7 +23,7 @@ from cylon_tpu.ops.join import join
 
 @pytest.fixture
 def pallas_interpret(monkeypatch):
-    monkeypatch.setenv("CYLON_PALLAS", "interpret")
+    monkeypatch.setenv("CYLON_PALLAS", "1")
 
 
 @pytest.fixture

@@ -544,3 +544,38 @@ def test_groupby_segscan_path_parity(rng, monkeypatch):
     got_seg = groupby_aggregate(t, ["k"], aggs,
                                 out_capacity=200).to_pandas()
     pd.testing.assert_frame_equal(got, got_seg)
+
+
+@pytest.mark.parametrize("platform,rows,wide", [
+    ("cpu", 1000, False), ("cpu", 4_000_000, True),
+    ("tpu", 1000, True), ("tpu", 4_000_000, True)])
+def test_wide_payloads_leave_the_sort_on_tpu_at_any_size(platform, rows,
+                                                          wide):
+    """Off the TPU wide payloads ride the sort below the row knee; on
+    the TPU a wide sort's compile time rules at every size."""
+    from cylon_tpu.ops.selection import use_gather_path
+    from cylon_tpu.platform import on_platform
+
+    with on_platform(platform):
+        assert use_gather_path(20, rows) is wide
+        assert not use_gather_path(4, rows)
+
+
+@pytest.mark.parametrize("platform,cols,gather", [
+    ("cpu", ("f64", "f64"), False), ("tpu", ("f64",), False),
+    ("tpu", ("f64", "f64"), True), ("tpu", ("i32", "i32", "i32"), True)])
+def test_tpu_group_sort_carries_one_64bit_value_column(platform, cols,
+                                                       gather):
+    """On the TPU the group sort carries at most one 64-bit value
+    column (each payload word costs compile time); wider value sets
+    are gathered through the sorted row index. Off the TPU the shared
+    width-and-rows knee still decides."""
+    from cylon_tpu.column import Column
+    from cylon_tpu.ops.groupby import _gather_values
+    from cylon_tpu.platform import on_platform
+
+    dt = {"f64": np.float64, "i32": np.int32}
+    src = {f"c{i}": Column.from_numpy(np.zeros(8, dt[c]))
+           for i, c in enumerate(cols)}
+    with on_platform(platform):
+        assert _gather_values(src, 1000) is gather

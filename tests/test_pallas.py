@@ -17,7 +17,7 @@ from cylon_tpu.ops import pallas_kernels as pk
 
 @pytest.fixture
 def pallas_interpret(monkeypatch):
-    monkeypatch.setenv("CYLON_PALLAS", "interpret")
+    monkeypatch.setenv("CYLON_PALLAS", "1")
 
 
 def test_row_hash_matches_jnp_chain(rng, pallas_interpret, monkeypatch):
@@ -145,8 +145,8 @@ def test_pair_max_scan_matches_u64_cummax(rng, pallas_interpret):
 
 
 def test_dist_join_under_interpret_mode(env8, rng, pallas_interpret):
-    """Distributed join on the mesh with CYLON_PALLAS=interpret: inside
-    shard_map the operands are device-varying, where the interpret
+    """Distributed join on the mesh with CYLON_PALLAS=1 (interpreted
+    on the CPU): inside shard_map the operands are device-varying, where the interpret
     evaluator cannot run the scan kernels — the gates must fall back to
     the XLA forms cleanly (regression: the pair-scan's cross-row
     combine once used a lax.scan whose unvarying carry failed the vma

@@ -28,6 +28,7 @@ from cylon_tpu import catalog, telemetry, views
 from cylon_tpu.errors import InvalidArgument, KeyError_
 from cylon_tpu.resilience import KILL_EXIT_CODE
 from cylon_tpu.table import Table
+from cylon_tpu.testing import assert_results_match
 from cylon_tpu.views import (combine_partials, finalize_twophase,
                              merge_delta, present)
 
@@ -45,14 +46,8 @@ def _clean():
 
 def _frames_equal(got, want, float_cols=()):
     """Exact on keys/counts, rtol=1e-9 on re-associated float sums."""
-    got = got.reset_index(drop=True)[list(want.columns)]
-    want = want.reset_index(drop=True)
-    for c in want.columns:
-        if c in float_cols:
-            np.testing.assert_allclose(got[c].to_numpy(),
-                                       want[c].to_numpy(), rtol=1e-9)
-        else:
-            assert list(got[c]) == list(want[c]), c
+    assert_results_match(got.reset_index(drop=True)[list(want.columns)],
+                         want, float_cols=float_cols)
 
 
 # ====================================================== merge algebra
