@@ -14,6 +14,7 @@ from cylon_tpu.errors import InvalidArgument
 from cylon_tpu.ops import kernels
 from cylon_tpu.ops.selection import _null_flags
 from cylon_tpu.table import Table
+from cylon_tpu.utils.tracing import scope
 
 AGGS = ("sum", "count", "min", "max", "mean", "var", "std", "nunique",
         "median", "quantile")
@@ -43,6 +44,7 @@ def _masked_quantile(data, ok, q):
     return jnp.where(n > 0, out, jnp.asarray(jnp.nan, f))
 
 
+@scope("aggregate")
 def table_aggregate(table: Table, col: str, op: str, quantile: float = 0.5):
     """Scalar aggregate of one column, skipping nulls/NaN. Returns a
     0-d jax array (device scalar; jit-safe). Op set mirrors

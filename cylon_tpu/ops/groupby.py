@@ -34,6 +34,7 @@ from cylon_tpu.ops.selection import (_null_flags, columns_to_payloads,
                                      payloads_to_columns, take_columns)
 from cylon_tpu.platform import platform_jit
 from cylon_tpu.table import Table
+from cylon_tpu.utils.tracing import scope
 
 #: ops supported (parity: aggregate_kernels.hpp:40-52 + pandas extras).
 #: "sumsq" is internal — the mergeable partial for distributed var/std.
@@ -157,6 +158,7 @@ def groupby_aggregate(table: Table, by: Sequence[str],
 
 @functools.partial(platform_jit, static_argnames=("by", "aggs", "out_cap",
                                                   "quantile", "segscan"))
+@scope("groupby")
 def _groupby_compiled(table: Table, *, by, aggs, out_cap,
                       quantile, segscan=False) -> Table:
     cap = table.capacity
@@ -190,15 +192,17 @@ def _groupby_compiled(table: Table, *, by, aggs, out_cap,
         payloads, pack = columns_to_payloads(src_cols, cap, lead=[iota],
                                              index_slot=0)
 
-    gid_s, num_groups, sorted_pl = kernels.group_sort(
-        keys, table.nrows, kvals, payloads)
+    with scope("groupby", "sort"):
+        gid_s, num_groups, sorted_pl = kernels.group_sort(
+            keys, table.nrows, kvals, payloads)
     orig_idx = sorted_pl[0]
-    if wide:
-        stab = take_columns(table, orig_idx, table.nrows,
-                            names=src_names)
-    else:
-        sorted_cols = payloads_to_columns(src_cols, sorted_pl, pack)
-        stab = Table(sorted_cols, table.nrows)
+    with scope("groupby", "permute"):
+        if wide:
+            stab = take_columns(table, orig_idx, table.nrows,
+                                names=src_names)
+        else:
+            sorted_cols = payloads_to_columns(src_cols, sorted_pl, pack)
+            stab = Table(sorted_cols, table.nrows)
 
     specs = []
     for spec in aggs:
@@ -207,11 +211,25 @@ def _groupby_compiled(table: Table, *, by, aggs, out_cap,
         if op not in AGG_OPS:
             raise InvalidArgument(f"unknown aggregation {op!r}")
 
-    if segscan:
-        out = _aggregate_scan(stab, table, by, specs, gid_s, num_groups,
-                              out_cap, quantile, orig_idx)
+    with scope("groupby", "reduce"):
+        if segscan:
+            out = _aggregate_scan(stab, table, by, specs, gid_s, num_groups,
+                                  out_cap, quantile, orig_idx)
+        else:
+            out = _aggregate_segments(stab, table, by, specs, gid_s,
+                                      num_groups, out_cap, quantile,
+                                      orig_idx, iota)
         return kernels.carry_overflow(Table(out, num_groups), table)
 
+
+def _aggregate_segments(stab: Table, table: Table, by, specs, gid_s,
+                        num_groups, out_cap: int, quantile, orig_idx,
+                        iota) -> dict:
+    """The segment-op path (CPU meshes, and the TPU above
+    :data:`SEGSCAN_MAX_ROWS`): one XLA segment reduction per aggregate
+    over the group-sorted layout (``stab``/``gid_s``); ``iota`` is the
+    original table's row index."""
+    cap = table.capacity
     big = jnp.int32(cap)
     first_pos = jax.ops.segment_min(jnp.where(gid_s < big, iota, big),
                                     gid_s, num_segments=out_cap,
@@ -229,7 +247,7 @@ def _groupby_compiled(table: Table, *, by, aggs, out_cap,
     for src, op, name in specs:
         out[name] = _aggregate_column(stab, src, op, gid_s, num_groups,
                                       out_cap, quantile)
-    return kernels.carry_overflow(Table(out, num_groups), table)
+    return out
 
 
 def _aggregate_scan(stab: Table, orig_table: Table, by, specs, gid_s,

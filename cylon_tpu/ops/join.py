@@ -42,6 +42,7 @@ from cylon_tpu.ops.selection import take_columns
 from cylon_tpu.platform import platform_jit
 from cylon_tpu.table import Table
 from cylon_tpu.utils.logging import get_logger
+from cylon_tpu.utils.tracing import scope
 
 #: one-shot flags for routing downgrades that used to be silent (or,
 #: historically, errors): warn the first time, count every time.
@@ -199,16 +200,14 @@ def join(left: Table, right: Table, config: JoinConfig | None = None, *,
     if kernel == "hash_bucketed":
         from cylon_tpu import telemetry
         from cylon_tpu.ops import hash_join
-        from cylon_tpu.utils import tracing as _tr
 
         if how == "inner" and cl <= cr:
             bkeys, bvals, brows = lkeys, lvals, left.nrows
         else:
             bkeys, bvals, brows = rkeys, rvals, right.nrows
-        with _tr.span("join.route"):
-            if hash_join.chain_overflow(bkeys, bvals, brows):
-                telemetry.counter("join.overflow_fallbacks").inc()
-                kernel = "sort"
+        if hash_join.chain_overflow(bkeys, bvals, brows):
+            telemetry.counter("join.overflow_fallbacks").inc()
+            kernel = "sort"
         telemetry.counter(
             "join.algorithm",
             kind=("hash->sort_overflow" if kernel == "sort"
@@ -227,6 +226,7 @@ def join(left: Table, right: Table, config: JoinConfig | None = None, *,
                                                   "how", "suffixes",
                                                   "out_cap", "algorithm",
                                                   "ordered"))
+@scope("join")
 def _join_compiled(left: Table, right: Table, *, left_on, right_on, how,
                    suffixes, out_cap, algorithm="sort",
                    ordered=True) -> Table:
@@ -243,17 +243,19 @@ def _join_compiled(left: Table, right: Table, *, left_on, right_on, how,
                 return _join_indices(lkeys, lvals, left.nrows, rkeys,
                                      rvals, right.nrows, how, out_cap,
                                      hash_first=False, ordered=ordered)
-        left_idx, right_idx, total = hash_join.bucketed_join_indices(
-            lkeys, lvals, left.nrows, rkeys, rvals, right.nrows, how,
-            out_cap, ordered, sort_fallback=sort_fb)
+        with scope("join", "match"):
+            left_idx, right_idx, total = hash_join.bucketed_join_indices(
+                lkeys, lvals, left.nrows, rkeys, rvals, right.nrows, how,
+                out_cap, ordered, sort_fallback=sort_fb)
     else:
         left_idx, right_idx, total = _join_indices(
             lkeys, lvals, left.nrows, rkeys, rvals, right.nrows, how,
             out_cap, hash_first=algorithm == "hash_sort",
             ordered=ordered)
-    res = _assemble(left, right, list(left_on), list(right_on),
-                    suffixes, left_idx, right_idx, total, how)
-    return kernels.carry_overflow(res, left, right)
+    with scope("join", "gather"):
+        res = _assemble(left, right, list(left_on), list(right_on),
+                        suffixes, left_idx, right_idx, total, how)
+        return kernels.carry_overflow(res, left, right)
 
 
 def _aligned_keys(left, right, left_on, right_on):
@@ -321,118 +323,122 @@ def _join_indices(lkeys, lvals, lrows, rkeys, rvals, rrows, how, out_cap,
     (inner/left: left-frame order; fullouter: the sorted key union with
     null keys last).
     """
-    cl = lkeys[0].shape[0]
-    cr = rkeys[0].shape[0]
-    ncomb = cl + cr
+    with scope("join", "keys"):
+        cl = lkeys[0].shape[0]
+        cr = rkeys[0].shape[0]
+        ncomb = cl + cr
 
-    ckeys = [jnp.concatenate([l, r]) for l, r in zip(lkeys, rkeys)]
-    cvals = []
-    for lv, rv, lk, rk in zip(lvals, rvals, lkeys, rkeys):
-        if lv is None and rv is None:
-            cvals.append(None)
-        else:
-            lv_ = jnp.ones(cl, bool) if lv is None else lv
-            rv_ = jnp.ones(cr, bool) if rv is None else rv
-            cvals.append(jnp.concatenate([lv_, rv_]))
-    cvalid = jnp.concatenate([kernels.valid_mask(cl, lrows),
-                              kernels.valid_mask(cr, rrows)])
+        ckeys = [jnp.concatenate([l, r]) for l, r in zip(lkeys, rkeys)]
+        cvals = []
+        for lv, rv, lk, rk in zip(lvals, rvals, lkeys, rkeys):
+            if lv is None and rv is None:
+                cvals.append(None)
+            else:
+                lv_ = jnp.ones(cl, bool) if lv is None else lv
+                rv_ = jnp.ones(cr, bool) if rv is None else rv
+                cvals.append(jnp.concatenate([lv_, rv_]))
+        cvalid = jnp.concatenate([kernels.valid_mask(cl, lrows),
+                                  kernels.valid_mask(cr, rrows)])
 
-    iota_c = jnp.arange(ncomb, dtype=jnp.int32)
+        iota_c = jnp.arange(ncomb, dtype=jnp.int32)
     # the row iota is BOTH the sub-order key (left indices < cl precede
     # right ones, so each group's left rows sort first) and the
     # original-row payload — one operand instead of a side flag plus a
     # payload; its uniqueness makes the order total, so the sort can
     # skip stability bookkeeping
     want_gid = ordered and how == "fullouter"
-    gid_s, _, (orig_u,) = kernels.group_sort(
-        ckeys, cvalid, cvals, hash_first=hash_first,
-        suborder=[iota_c.astype(jnp.uint32)], stable=False)
-    orig_s = orig_u.astype(jnp.int32)
+    with scope("join", "sort"):
+        gid_s, _, (orig_u,) = kernels.group_sort(
+            ckeys, cvalid, cvals, hash_first=hash_first,
+            suborder=[iota_c.astype(jnp.uint32)], stable=False)
+    with scope("join", "match"):
+        orig_s = orig_u.astype(jnp.int32)
 
-    valid_s = gid_s < ncomb
-    is_r = valid_s & (orig_s >= cl)
-    is_l = valid_s & (orig_s < cl)
-    boundary = valid_s & ((gid_s != jnp.roll(gid_s, 1)) | (iota_c == 0))
-    is_end = valid_s & (jnp.roll(boundary, -1) | ~jnp.roll(valid_s, -1)
-                        | (iota_c == ncomb - 1))
+        valid_s = gid_s < ncomb
+        is_r = valid_s & (orig_s >= cl)
+        is_l = valid_s & (orig_s < cl)
+        boundary = valid_s & ((gid_s != jnp.roll(gid_s, 1)) | (iota_c == 0))
+        is_end = valid_s & (jnp.roll(boundary, -1) | ~jnp.roll(valid_s, -1)
+                            | (iota_c == ncomb - 1))
 
-    cum_r = kernels.fast_cumsum(is_r.astype(jnp.int32))
-    cum_l = kernels.fast_cumsum(is_l.astype(jnp.int32))
-    s_g = kernels.forward_fill(boundary, iota_c)
-    rb = kernels.forward_fill(boundary, cum_r - is_r)
-    lb = kernels.forward_fill(boundary, cum_l - is_l)
-    rcnt = kernels.reverse_fill(is_end, cum_r) - rb    # rights in my group
-    lcnt = kernels.reverse_fill(is_end, cum_l) - lb
-    right_start = s_g + lcnt   # sorted position of the group's first right
+        cum_r = kernels.fast_cumsum(is_r.astype(jnp.int32))
+        cum_l = kernels.fast_cumsum(is_l.astype(jnp.int32))
+        s_g = kernels.forward_fill(boundary, iota_c)
+        rb = kernels.forward_fill(boundary, cum_r - is_r)
+        lb = kernels.forward_fill(boundary, cum_l - is_l)
+        rcnt = kernels.reverse_fill(is_end, cum_r) - rb    # rights in my group
+        lcnt = kernels.reverse_fill(is_end, cum_l) - lb
+        right_start = s_g + lcnt   # sorted position of the group's first right
 
-    match_counts = jnp.where(is_l, rcnt, 0)
-    if how == "inner":
-        ecounts = match_counts
-    else:  # left / fullouter: unmatched left rows still emit one row
-        ecounts = jnp.where(is_l, jnp.maximum(match_counts, 1), 0)
+        match_counts = jnp.where(is_l, rcnt, 0)
+        if how == "inner":
+            ecounts = match_counts
+        else:  # left / fullouter: unmatched left rows still emit one row
+            ecounts = jnp.where(is_l, jnp.maximum(match_counts, 1), 0)
 
-    # run-length expansion (row i emits ecounts[i] output slots, the
-    # static-shape stand-in for the reference's dynamic index vectors,
-    # join/join_utils.hpp:34): scatter each run's sorted position at its
-    # start offset, running-max fills the run; the per-parent values
-    # (run offset, match count, right-run start, original row) ride ONE
-    # packed row-gather
-    offs = kernels.exclusive_cumsum(ecounts)
-    total = (offs[-1] + ecounts[-1] if ncomb else jnp.int32(0)
-             ).astype(jnp.int32)
-    start = jnp.where(ecounts > 0, offs, out_cap).astype(jnp.int32)
-    mark = jnp.full(out_cap, -1, jnp.int32).at[start].max(iota_c,
-                                                          mode="drop")
-    parent = jnp.clip(kernels.fast_cummax(mark), 0, max(ncomb - 1, 0))
-    # the order-key gid column rides the packed gather only when the
-    # fullouter restore needs it (gathers are priced ~10x elementwise)
-    pcols = [offs.astype(jnp.int32), match_counts, right_start, orig_s]
-    if want_gid:
-        pcols.append(gid_s)
-    packed = jnp.stack(pcols, axis=1)           # [ncomb, 4 or 5]
-    g = packed[parent]                          # one packed row-gather
-    j = jnp.arange(out_cap, dtype=jnp.int32)
-    within = j - g[:, 0]
-    matched = g[:, 1] > 0
-    r_pos = jnp.clip(g[:, 2] + within, 0, max(ncomb - 1, 0))
-    right_idx = jnp.where(matched, orig_s[r_pos] - cl, -1)
-    left_idx = g[:, 3]
-    slot_gid = g[:, 4] if want_gid else None
-
-    if how == "fullouter":
-        extra_mask = is_r & (lcnt == 0)
-        perm_s, n_extra = kernels.compact_mask(extra_mask, valid_s)
-        shifted = jnp.clip(j - total, 0, max(ncomb - 1, 0))
-        ecols = [orig_s] + ([gid_s] if want_gid else [])
-        epair = jnp.stack(ecols, axis=1)[perm_s[shifted]]
-        in_main = j < total
-        left_idx = jnp.where(in_main, left_idx, -1)
-        right_idx = jnp.where(in_main, right_idx, epair[:, 0] - cl)
+        # run-length expansion (row i emits ecounts[i] output slots, the
+        # static-shape stand-in for the reference's dynamic index vectors,
+        # join/join_utils.hpp:34): scatter each run's sorted position at its
+        # start offset, running-max fills the run; the per-parent values
+        # (run offset, match count, right-run start, original row) ride ONE
+        # packed row-gather
+        offs = kernels.exclusive_cumsum(ecounts)
+        total = (offs[-1] + ecounts[-1] if ncomb else jnp.int32(0)
+                 ).astype(jnp.int32)
+        start = jnp.where(ecounts > 0, offs, out_cap).astype(jnp.int32)
+        mark = jnp.full(out_cap, -1, jnp.int32).at[start].max(iota_c,
+                                                              mode="drop")
+        parent = jnp.clip(kernels.fast_cummax(mark), 0, max(ncomb - 1, 0))
+        # the order-key gid column rides the packed gather only when the
+        # fullouter restore needs it (gathers are priced ~10x elementwise)
+        pcols = [offs.astype(jnp.int32), match_counts, right_start, orig_s]
         if want_gid:
-            slot_gid = jnp.where(in_main, slot_gid, epair[:, 1])
-        total = total + n_extra
+            pcols.append(gid_s)
+        packed = jnp.stack(pcols, axis=1)           # [ncomb, 4 or 5]
+        g = packed[parent]                          # one packed row-gather
+        j = jnp.arange(out_cap, dtype=jnp.int32)
+        within = j - g[:, 0]
+        matched = g[:, 1] > 0
+        r_pos = jnp.clip(g[:, 2] + within, 0, max(ncomb - 1, 0))
+        right_idx = jnp.where(matched, orig_s[r_pos] - cl, -1)
+        left_idx = g[:, 3]
+        slot_gid = g[:, 4] if want_gid else None
+
+        if how == "fullouter":
+            extra_mask = is_r & (lcnt == 0)
+            perm_s, n_extra = kernels.compact_mask(extra_mask, valid_s)
+            shifted = jnp.clip(j - total, 0, max(ncomb - 1, 0))
+            ecols = [orig_s] + ([gid_s] if want_gid else [])
+            epair = jnp.stack(ecols, axis=1)[perm_s[shifted]]
+            in_main = j < total
+            left_idx = jnp.where(in_main, left_idx, -1)
+            right_idx = jnp.where(in_main, right_idx, epair[:, 0] - cl)
+            if want_gid:
+                slot_gid = jnp.where(in_main, slot_gid, epair[:, 1])
+            total = total + n_extra
 
     if ordered:
-        # restore pandas order with one stable sort of the index pairs.
-        # inner/left: left-frame order (slots of one left row keep
-        # their right-frame order by stability). fullouter: pandas
-        # sorts the key union lexicographically with nulls last per
-        # level — exactly GROUP order (group_sort ranks null keys with
-        # the max word per level), so the group id is the sort key
-        # (right-only extras interleave by key; within a key the
-        # left-frame emission order is preserved by stability). Valid
-        # slots are contiguous at the front either way, so
-        # ordered=False simply skips this.
-        valid_slot = j < total
-        if how == "fullouter":
-            okey = jnp.where(valid_slot, slot_gid.astype(jnp.uint32),
-                             jnp.uint32(0xFFFFFFFF))
-        else:
-            # every valid inner/left slot has a left-row parent
-            okey = jnp.where(valid_slot, left_idx.astype(jnp.uint32),
-                             jnp.uint32(0xFFFFFFFF))
-        _, left_idx, right_idx = jax.lax.sort(
-            (okey, left_idx, right_idx), num_keys=1, is_stable=True)
+        with scope("join", "order"):
+            # restore pandas order with one stable sort of the index pairs.
+            # inner/left: left-frame order (slots of one left row keep
+            # their right-frame order by stability). fullouter: pandas
+            # sorts the key union lexicographically with nulls last per
+            # level — exactly GROUP order (group_sort ranks null keys with
+            # the max word per level), so the group id is the sort key
+            # (right-only extras interleave by key; within a key the
+            # left-frame emission order is preserved by stability). Valid
+            # slots are contiguous at the front either way, so
+            # ordered=False simply skips this.
+            valid_slot = j < total
+            if how == "fullouter":
+                okey = jnp.where(valid_slot, slot_gid.astype(jnp.uint32),
+                                 jnp.uint32(0xFFFFFFFF))
+            else:
+                # every valid inner/left slot has a left-row parent
+                okey = jnp.where(valid_slot, left_idx.astype(jnp.uint32),
+                                 jnp.uint32(0xFFFFFFFF))
+            _, left_idx, right_idx = jax.lax.sort(
+                (okey, left_idx, right_idx), num_keys=1, is_stable=True)
 
     return left_idx, right_idx, total
 

@@ -18,6 +18,7 @@ from cylon_tpu.errors import InvalidArgument
 from cylon_tpu.ops import kernels
 from cylon_tpu.platform import platform_jit
 from cylon_tpu.table import Table
+from cylon_tpu.utils.tracing import scope
 
 
 def _packable(data: jax.Array) -> bool:
@@ -253,17 +254,21 @@ def permute_by_sort(table: Table, operands, nrows_out) -> Table:
 
 
 @jax.jit
+@scope("filter")
 def filter_table(table: Table, mask: jax.Array) -> Table:
     """Keep rows where mask is True, preserving order (parity: the
     filter path of ``python/pycylon/data/compute.pyx:212``). Jitted:
     one compiled program; the compaction is a stable u8-key sort with
     the columns as payload (see permute_by_sort)."""
-    cap = table.capacity
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    keep = mask & (iota < table.nrows)
-    count = keep.sum(dtype=jnp.int32)
-    return kernels.carry_overflow(
-        permute_by_sort(table, ((~keep).astype(jnp.uint8),), count), table)
+    with scope("filter", "mask"):
+        cap = table.capacity
+        iota = jnp.arange(cap, dtype=jnp.int32)
+        keep = mask & (iota < table.nrows)
+        count = keep.sum(dtype=jnp.int32)
+    with scope("filter", "compact"):
+        return kernels.carry_overflow(
+            permute_by_sort(table, ((~keep).astype(jnp.uint8),), count),
+            table)
 
 
 def sort_table(table: Table, by: Sequence[str], ascending=True,
@@ -304,15 +309,18 @@ def sort_key_operands(c: Column, asc: bool,
 
 @functools.partial(platform_jit, static_argnames=("by", "ascending",
                                                   "na_position"))
+@scope("sort")
 def _sort_compiled(table: Table, *, by, ascending, na_position) -> Table:
-    okeys = []
-    for name, asc in zip(by, ascending):
-        okeys.extend(sort_key_operands(table.column(name), asc,
-                                       na_position))
-    padding = (~kernels.valid_mask(table.capacity, table.nrows)
-               ).astype(jnp.uint8)
-    operands = kernels.pack_order_keys([padding] + okeys)
-    return permute_by_sort(table, operands, table.nrows)
+    with scope("sort", "keys"):
+        okeys = []
+        for name, asc in zip(by, ascending):
+            okeys.extend(sort_key_operands(table.column(name), asc,
+                                           na_position))
+        padding = (~kernels.valid_mask(table.capacity, table.nrows)
+                   ).astype(jnp.uint8)
+        operands = kernels.pack_order_keys([padding] + okeys)
+    with scope("sort", "permute"):
+        return permute_by_sort(table, operands, table.nrows)
 
 
 def _null_flags(c: Column) -> jax.Array | None:
@@ -385,6 +393,7 @@ def concat_tables(tables: Sequence[Table], capacity: int | None = None) -> Table
     return kernels.carry_overflow(Table(cols, total), *tables)
 
 
+@scope("take")
 def head(table: Table, n: int) -> Table:
     """First n valid rows (valid rows are always the leading rows)."""
     return table.with_nrows(jnp.minimum(table.nrows, n))
@@ -407,6 +416,7 @@ def sample(table: Table, n: int) -> Table:
     return take_columns(table, idx, take_n)
 
 
+@scope("take")
 def take(table: Table, idx: jax.Array) -> Table:
     """Public gather-by-indices (parity: arrow Take used throughout
     reference join/sort paths)."""

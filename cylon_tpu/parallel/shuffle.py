@@ -38,6 +38,7 @@ from cylon_tpu.column import Column
 from cylon_tpu.context import WORKER_AXIS
 from cylon_tpu.ops import kernels
 from cylon_tpu.table import Table
+from cylon_tpu.utils.tracing import scope
 
 
 def _use_ragged() -> bool:
@@ -63,6 +64,7 @@ def _use_ragged() -> bool:
     return ragged
 
 
+@scope("exchange")
 def exchange_arrays(arrays, pid, n_local, out_cap: int,
                     bucket_cap: int | None = None,
                     axis_name=WORKER_AXIS,
@@ -114,29 +116,32 @@ def exchange_arrays(arrays, pid, n_local, out_cap: int,
     w = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     cap = pid.shape[0]
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    valid = iota < n_local
-    pid = jnp.where(valid, pid, w).astype(jnp.int32)
+    with scope("exchange", "partition"):
+        iota = jnp.arange(cap, dtype=jnp.int32)
+        valid = iota < n_local
+        pid = jnp.where(valid, pid, w).astype(jnp.int32)
 
-    # group rows by destination (one stable sort, parity with the
-    # reference's per-target Split kernels, partition/partition.cpp:26)
-    order = kernels.sort_perm([pid], valid)
-    pid_sorted = pid[order]
-    counts = jax.ops.segment_sum(jnp.ones(cap, jnp.int32), pid,
-                                 num_segments=w)
-    cmat = jax.lax.all_gather(counts, axis_name)          # [W sender, W dest]
-    recv_sizes = cmat[:, me]
-    n_recv_true = recv_sizes.sum()
+        # group rows by destination (one stable sort, parity with the
+        # reference's per-target Split kernels, partition/partition.cpp:26)
+        order = kernels.sort_perm([pid], valid)
+        pid_sorted = pid[order]
+        counts = jax.ops.segment_sum(jnp.ones(cap, jnp.int32), pid,
+                                     num_segments=w)
+        cmat = jax.lax.all_gather(counts, axis_name)  # [W sender, W dest]
+        recv_sizes = cmat[:, me]
+        n_recv_true = recv_sizes.sum()
 
     if _use_ragged():
         # Run on a v5e chip by chip_smoke.py (W=1 mesh forced through
         # the exchange; string, i64 and f64 payloads). All columns ride
         # ONE packed u32 word matrix: one destination-order gather and
         # ONE ragged collective per exchange instead of ~2 per column.
-        packed, spec = _pack_words(arrays)
-        got = _ragged_words(packed[order], counts, cmat, me, out_cap,
-                            axis_name)
-        outs = _unpack_words(got, spec)
+        with scope("exchange", "pack"):
+            packed, spec = _pack_words(arrays)
+            rows = packed[order]
+        got = _ragged_words(rows, counts, cmat, me, out_cap, axis_name)
+        with scope("exchange", "unpack"):
+            outs = _unpack_words(got, spec)
         n_recv = jnp.where(n_recv_true > out_cap, out_cap + 1, n_recv_true)
         return outs, n_recv.astype(jnp.int32)
 
@@ -152,35 +157,37 @@ def exchange_arrays(arrays, pid, n_local, out_cap: int,
 
     # ---- single-round padded path: [W, bucket_cap] blocks ----
     b = bucket_cap
-    start = kernels.exclusive_cumsum(counts)
-    pid_safe = jnp.clip(pid_sorted, 0, w - 1)
-    within = jnp.arange(cap, dtype=jnp.int32) - start[pid_safe]
-    slot = jnp.where((pid_sorted < w) & (within < b),
-                     pid_safe * b + within, w * b)      # w*b = dropped
-    overflow_local = (counts > b).any()
+    with scope("exchange", "pack"):
+        start = kernels.exclusive_cumsum(counts)
+        pid_safe = jnp.clip(pid_sorted, 0, w - 1)
+        within = jnp.arange(cap, dtype=jnp.int32) - start[pid_safe]
+        slot = jnp.where((pid_sorted < w) & (within < b),
+                         pid_safe * b + within, w * b)  # w*b = dropped
+        overflow_local = (counts > b).any()
 
-    recv_block_sizes = jnp.minimum(recv_sizes, b)
-    pos = jnp.arange(w * b, dtype=jnp.int32)
-    recv_valid = (pos % b) < recv_block_sizes[pos // b]
-    keep = (~recv_valid).astype(jnp.uint8)
-
-    packed, spec = _pack_words(arrays)
-    nw = packed.shape[1]
-    psorted = packed[order]
-    buf = jnp.zeros((w * b, nw), jnp.uint32).at[slot].set(psorted,
-                                                          mode="drop")
-    swapped = jax.lax.all_to_all(buf.reshape(w, b, nw), axis_name,
-                                 split_axis=0, concat_axis=0)
-    flat = swapped.reshape(w * b, nw)
-    _, compact_perm = jax.lax.sort(
-        (keep, jnp.arange(w * b, dtype=jnp.int32)), num_keys=1)
-    compacted = flat[compact_perm]
-    if w * b >= out_cap:
-        compacted = compacted[:out_cap]
-    else:
-        compacted = jnp.concatenate(
-            [compacted, jnp.zeros((out_cap - w * b, nw), jnp.uint32)])
-    outs = _unpack_words(compacted, spec)
+        packed, spec = _pack_words(arrays)
+        nw = packed.shape[1]
+        psorted = packed[order]
+        buf = jnp.zeros((w * b, nw), jnp.uint32).at[slot].set(psorted,
+                                                              mode="drop")
+    with scope("exchange", "all-to-all"):
+        swapped = jax.lax.all_to_all(buf.reshape(w, b, nw), axis_name,
+                                     split_axis=0, concat_axis=0)
+    with scope("exchange", "unpack"):
+        recv_block_sizes = jnp.minimum(recv_sizes, b)
+        pos = jnp.arange(w * b, dtype=jnp.int32)
+        recv_valid = (pos % b) < recv_block_sizes[pos // b]
+        keep = (~recv_valid).astype(jnp.uint8)
+        flat = swapped.reshape(w * b, nw)
+        _, compact_perm = jax.lax.sort(
+            (keep, jnp.arange(w * b, dtype=jnp.int32)), num_keys=1)
+        compacted = flat[compact_perm]
+        if w * b >= out_cap:
+            compacted = compacted[:out_cap]
+        else:
+            compacted = jnp.concatenate(
+                [compacted, jnp.zeros((out_cap - w * b, nw), jnp.uint32)])
+        outs = _unpack_words(compacted, spec)
 
     # fold all failure modes into an impossible row count:
     # - a (sender,dest) bucket overflowed somewhere (psum of flags)
@@ -220,38 +227,43 @@ def _ragged_words(rows, counts, cmat, me, out_cap: int, axis_name):
     send_w = counts * nw
     send_c = nchunks(send_w)
     in_c = kernels.exclusive_cumsum(send_c)
-    in_w = kernels.exclusive_cumsum(send_w)
-    # pad the flat stream so destination d's words start at chunk in_c[d]
-    n_send = (-(-cap * nw // size) + w) * size
-    pos = jnp.arange(n_send, dtype=jnp.int32)
-    d = jnp.minimum(jnp.searchsorted(in_c + send_c, pos // size,
-                                     side="right"), w - 1)
-    within = pos - in_c[d] * size
-    flat = rows.reshape(-1)
-    send = jnp.where(within < send_w[d],
-                     flat[jnp.clip(in_w[d] + within, 0, flat.shape[0] - 1)],
-                     jnp.uint32(0)).reshape(-1, size)
+    with scope("exchange", "pack"):
+        in_w = kernels.exclusive_cumsum(send_w)
+        # pad the flat stream so destination d's words start at chunk
+        # in_c[d]
+        n_send = (-(-cap * nw // size) + w) * size
+        pos = jnp.arange(n_send, dtype=jnp.int32)
+        d = jnp.minimum(jnp.searchsorted(in_c + send_c, pos // size,
+                                         side="right"), w - 1)
+        within = pos - in_c[d] * size
+        flat = rows.reshape(-1)
+        send = jnp.where(
+            within < send_w[d],
+            flat[jnp.clip(in_w[d] + within, 0, flat.shape[0] - 1)],
+            jnp.uint32(0)).reshape(-1, size)
 
     cm_c = nchunks(cmat * nw)
     out_c = (jnp.cumsum(cm_c, axis=0) - cm_c)[me, :]
     recv_c = cm_c[:, me]
-    buf = jnp.zeros((-(-out_cap * nw // size) + w, size), jnp.uint32)
-    got = jax.lax.ragged_all_to_all(send, buf, in_c, send_c, out_c,
-                                    recv_c, axis_name=axis_name)
+    with scope("exchange", "all-to-all"):
+        buf = jnp.zeros((-(-out_cap * nw // size) + w, size), jnp.uint32)
+        got = jax.lax.ragged_all_to_all(send, buf, in_c, send_c, out_c,
+                                        recv_c, axis_name=axis_name)
 
-    # compact: sender s's words start at chunk roff_c[s]
-    recv_w = cmat[:, me] * nw
-    roff_c = kernels.exclusive_cumsum(recv_c)
-    roff_w = kernels.exclusive_cumsum(recv_w)
-    q = jnp.arange(out_cap * nw, dtype=jnp.int32)
-    src = jnp.minimum(jnp.searchsorted(roff_w + recv_w, q, side="right"),
-                      w - 1)
-    got = got.reshape(-1)
-    out = jnp.where(q < recv_w.sum(),
-                    got[jnp.clip(roff_c[src] * size + q - roff_w[src], 0,
-                                 got.shape[0] - 1)],
-                    jnp.uint32(0))
-    return out.reshape(out_cap, nw)
+    with scope("exchange", "unpack"):
+        # compact: sender s's words start at chunk roff_c[s]
+        recv_w = cmat[:, me] * nw
+        roff_c = kernels.exclusive_cumsum(recv_c)
+        roff_w = kernels.exclusive_cumsum(recv_w)
+        q = jnp.arange(out_cap * nw, dtype=jnp.int32)
+        src = jnp.minimum(jnp.searchsorted(roff_w + recv_w, q, side="right"),
+                          w - 1)
+        got = got.reshape(-1)
+        out = jnp.where(q < recv_w.sum(),
+                        got[jnp.clip(roff_c[src] * size + q - roff_w[src], 0,
+                                     got.shape[0] - 1)],
+                        jnp.uint32(0))
+        return out.reshape(out_cap, nw)
 
 
 def transport_words(table) -> int:
@@ -342,12 +354,13 @@ def _exchange_padded_chunked(arrays, pid_sorted, order, n_recv_true,
 
     # per-(round, dest) send counts, and everyone's view of them:
     # cmat_rounds[s, c, d] = rows sender s ships to d in round c
-    chunk_of = jnp.arange(nch * b, dtype=jnp.int32) // b
-    seg = chunk_of * (w + 1) + jnp.minimum(pid_pad, w)
-    counts_cd = jax.ops.segment_sum(
-        jnp.ones(nch * b, jnp.int32), seg,
-        num_segments=nch * (w + 1)).reshape(nch, w + 1)[:, :w]
-    cmat_rounds = jax.lax.all_gather(counts_cd, axis_name)  # [W, C, W]
+    with scope("exchange", "partition"):
+        chunk_of = jnp.arange(nch * b, dtype=jnp.int32) // b
+        seg = chunk_of * (w + 1) + jnp.minimum(pid_pad, w)
+        counts_cd = jax.ops.segment_sum(
+            jnp.ones(nch * b, jnp.int32), seg,
+            num_segments=nch * (w + 1)).reshape(nch, w + 1)[:, :w]
+        cmat_rounds = jax.lax.all_gather(counts_cd, axis_name)  # [W, C, W]
     recv_mat = cmat_rounds[:, :, me]                        # [W, C]
     # final offset of (sender s, round c)'s first row on this shard
     sender_tot = recv_mat.sum(axis=1)
@@ -360,36 +373,41 @@ def _exchange_padded_chunked(arrays, pid_sorted, order, n_recv_true,
 
     # all columns ride one packed u32 word matrix: one gather into
     # destination order, one all_to_all per round (not ~2 per column)
-    packed, spec = _pack_words(arrays)
-    nw = packed.shape[1]
-    psorted = packed[order]
-    if padn:
-        psorted = jnp.concatenate(
-            [psorted, jnp.zeros((padn, nw), jnp.uint32)])
+    with scope("exchange", "pack"):
+        packed, spec = _pack_words(arrays)
+        nw = packed.shape[1]
+        psorted = packed[order]
+        if padn:
+            psorted = jnp.concatenate(
+                [psorted, jnp.zeros((padn, nw), jnp.uint32)])
     out_buf = jnp.zeros((out_cap, nw), jnp.uint32)
 
     for c in range(nch):
         sl = slice(c * b, (c + 1) * b)
-        pidc = pid_pad[sl]
-        countsc = counts_cd[c]
-        startc = kernels.exclusive_cumsum(countsc)
-        pidc_safe = jnp.clip(pidc, 0, w - 1)
-        within = jnp.arange(b, dtype=jnp.int32) - startc[pidc_safe]
-        slot = jnp.where(pidc < w, pidc_safe * b + within, w * b)
-        rvalid = r_idx < recv_mat[s_idx, c]
-        target = row_base[s_idx, c] + r_idx
-        # invalid / overflowing rows route to index out_cap: out of
-        # bounds for the receive buffer, dropped by mode="drop" — the
-        # n_recv fold below still reports the true total
-        target = jnp.where(rvalid, target, out_cap).astype(jnp.int32)
-        buf = jnp.zeros((w * b, nw), jnp.uint32)
-        buf = buf.at[slot].set(psorted[sl], mode="drop")
-        swapped = jax.lax.all_to_all(buf.reshape(w, b, nw), axis_name,
-                                     split_axis=0, concat_axis=0)
-        flat = swapped.reshape(w * b, nw)
-        out_buf = out_buf.at[target].set(flat, mode="drop")
+        with scope("exchange", "pack"):
+            pidc = pid_pad[sl]
+            countsc = counts_cd[c]
+            startc = kernels.exclusive_cumsum(countsc)
+            pidc_safe = jnp.clip(pidc, 0, w - 1)
+            within = jnp.arange(b, dtype=jnp.int32) - startc[pidc_safe]
+            slot = jnp.where(pidc < w, pidc_safe * b + within, w * b)
+            buf = jnp.zeros((w * b, nw), jnp.uint32)
+            buf = buf.at[slot].set(psorted[sl], mode="drop")
+        with scope("exchange", "all-to-all"):
+            swapped = jax.lax.all_to_all(buf.reshape(w, b, nw), axis_name,
+                                         split_axis=0, concat_axis=0)
+        with scope("exchange", "unpack"):
+            rvalid = r_idx < recv_mat[s_idx, c]
+            target = row_base[s_idx, c] + r_idx
+            # invalid / overflowing rows route to index out_cap: out of
+            # bounds for the receive buffer, dropped by mode="drop" —
+            # the n_recv fold below still reports the true total
+            target = jnp.where(rvalid, target, out_cap).astype(jnp.int32)
+            flat = swapped.reshape(w * b, nw)
+            out_buf = out_buf.at[target].set(flat, mode="drop")
 
-    outs = _unpack_words(out_buf, spec)
+    with scope("exchange", "unpack"):
+        outs = _unpack_words(out_buf, spec)
     n_recv = jnp.where(n_recv_true > out_cap, out_cap + 1, n_recv_true)
     return outs, n_recv.astype(jnp.int32)
 

@@ -42,6 +42,7 @@ import jax
 from cylon_tpu import telemetry
 from cylon_tpu.errors import OutOfCapacity
 from cylon_tpu.telemetry import trace as _trace
+from cylon_tpu.utils import tracing as _tracing
 
 __all__ = ["capacity_scale", "current_scale", "compile_query",
            "CompiledQuery", "MAX_SCALE", "note_overflow",
@@ -430,19 +431,29 @@ class CompiledQuery:
                     _collect_flags(flags):
                 out = fn(*(slots[i] for i in range(n)),
                          **dict(static_kw), **dyn_kw)
-            bad = functools.reduce(jax.numpy.logical_or, flags,
-                                   jnp.zeros((), bool))
+            with _tracing.scope("plan"):
+                bad = functools.reduce(jax.numpy.logical_or, flags,
+                                       jnp.zeros((), bool))
             return out, bad
 
-        self._jitted = jax.jit(traced, static_argnums=(0, 1, 2, 3))
         # the bucket slice is a SEPARATE tiny program composed after
         # the main one (an extra async dispatch, ~free): folding it
         # into `traced` would recompile the whole query — minutes of
         # XLA time for a big TPC-H program — the first time its result
         # sizes are known
-        self._slicer = jax.jit(
-            lambda buckets, out: _apply_buckets(out, buckets),
-            static_argnums=0)
+        def slicer(buckets, out):
+            with _tracing.scope("plan"):
+                return _apply_buckets(out, buckets)
+
+        # each program is named after the query (jit(q1), jit(q1_slice)),
+        # so a device trace says which query an operation belongs to
+        name = getattr(fn, "__name__", "query")
+        if not name.isidentifier():
+            name = "query"  # a lambda
+        traced.__name__ = traced.__qualname__ = name
+        slicer.__name__ = slicer.__qualname__ = f"{name}_slice"
+        self._jitted = jax.jit(traced, static_argnums=(0, 1, 2, 3))
+        self._slicer = jax.jit(slicer, static_argnums=0)
 
     def invalidate(self) -> None:
         """Drop the scale/shape/size memos (one lock hold). The views
@@ -457,6 +468,30 @@ class CompiledQuery:
             self._compiled.clear()
             self._size_memo.clear()
 
+    def lower(self, *args, **kwargs):
+        """The query's program as a call with these arguments would
+        dispatch it next (its memoized scale, its row hint), lowered
+        and not run: ``.as_text()`` shows its HLO, ``.compile()``
+        compiles it."""
+        dyn_pos, static_pos, static_kw, dyn_kw = _split_args(args, kwargs)
+        with self._mu:
+            scale = self._scale_memo.get((static_pos, static_kw), 1)
+        return self._jitted.lower(scale, self._row_hint(dyn_pos, dyn_kw),
+                                  static_pos, static_kw, tuple(dyn_pos),
+                                  **dyn_kw)
+
+    def _row_hint(self, dyn_pos, dyn_kw):
+        """The count-driven row bucket, which rides the compile key:
+        pow2 bucketing means it changes (and retraces) only when the
+        input's true row count crosses a power of two, exactly like the
+        capacity-scale ladder bounds its shape space. check=False
+        queries skip the probe entirely: they promise no host sync, and
+        with no overflow check there is no regrow ladder to repair a
+        hint-shrunk bound that real data outgrows."""
+        if self._check and tight_enabled() and adaptive_enabled():
+            return _input_row_bucket(dyn_pos, dyn_kw)
+        return None
+
     def __call__(self, *args, **kwargs):
         import numpy as np
 
@@ -470,16 +505,7 @@ class CompiledQuery:
         with self._mu:
             scale = self._scale_memo.get(key, 1)
             buckets = self._size_memo.get(key) if self._check else None
-        # the count-driven row bucket rides the compile key: pow2
-        # bucketing means it changes (and retraces) only when the
-        # input's true row count crosses a power of two, exactly like
-        # the capacity-scale ladder bounds its shape space. check=False
-        # queries skip the probe entirely: they promise no host sync,
-        # and with no overflow check there is no regrow ladder to
-        # repair a hint-shrunk bound that real data outgrows
-        hint = (_input_row_bucket(dyn_pos, dyn_kw)
-                if self._check and tight_enabled()
-                and adaptive_enabled() else None)
+        hint = self._row_hint(dyn_pos, dyn_kw)
         shape_sig = tuple(
             (getattr(x, "shape", None), str(getattr(x, "dtype", "")))
             for x in jax.tree_util.tree_leaves((tuple(dyn_pos),

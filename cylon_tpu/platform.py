@@ -52,7 +52,9 @@ def platform_jit(fn=None, *, static_argnames=()):
     cache is keyed only on avals + static args — a jaxpr traced for one
     platform would silently be reused for another. Every module-level
     jitted operator that can make such a decision goes through this
-    wrapper instead of ``jax.jit``.
+    wrapper instead of ``jax.jit``. The program keeps ``fn``'s name
+    (``jit(_join_compiled)``), so a device trace says which operator
+    ran.
     """
     if fn is None:
         return functools.partial(platform_jit,
@@ -61,6 +63,10 @@ def platform_jit(fn=None, *, static_argnames=()):
     def keyed(_pk, *args, **kwargs):
         del _pk  # cache key only; dispatch reads the ambient platform
         return fn(*args, **kwargs)
+
+    # the name only: functools.wraps would also hand jit fn's signature
+    # (through __wrapped__), against which static_argnums=(0,) is wrong
+    keyed.__name__ = keyed.__qualname__ = fn.__name__
 
     jitted = jax.jit(keyed, static_argnums=(0,),
                      static_argnames=tuple(static_argnames))

@@ -17,6 +17,7 @@ import numpy as np
 from cylon_tpu import dtypes
 from cylon_tpu.column import Column
 from cylon_tpu.errors import InvalidArgument, TypeError_
+from cylon_tpu.utils.tracing import scope
 
 
 class Series:
@@ -95,6 +96,7 @@ class Series:
     def _valid(self) -> jax.Array | None:
         return self._col.validity
 
+    @scope("project")
     def _binop(self, other, fn: Callable, out_kind=None) -> "Series":
         c = self._col
         if c.dtype.is_dictionary or c.dtype.is_bytes:
@@ -131,6 +133,7 @@ class Series:
     def __neg__(self): return self._binop(0, lambda a, _: jnp.negative(a))
     def __abs__(self): return self._binop(0, lambda a, _: jnp.abs(a))
 
+    @scope("project")
     def _cmp_op(self, o, name: str, fn: Callable) -> "Series":
         """Comparison dispatch: device-bytes string columns compare by
         big-endian word order on device (bytewise string order — the
@@ -246,6 +249,7 @@ class Series:
         return Series._wrap(col, count, self.name)
 
     # -- membership / map ------------------------------------------------
+    @scope("project")
     def isin(self, values) -> "Series":
         """Parity: ``compute.pyx`` is_in (:702). A null-ish probe value
         (None / NaN) matches null rows, like pandas isin([None]); a

@@ -1,4 +1,4 @@
-"""Runtime utilities: logging and op tracing/profiling.
+"""Runtime utilities: logging and op tracing.
 
 Reference analog: ``cpp/src/cylon/util/`` (logging.{hpp,cpp} glog wrap,
 macros) plus the inline ``std::chrono`` op timing at table boundaries
@@ -7,8 +7,8 @@ macros) plus the inline ``std::chrono`` op timing at table boundaries
 
 from cylon_tpu.utils.logging import (disable_logging, get_logger,
                                      init_logging, log_level)
-from cylon_tpu.utils.tracing import (profile_to, report, reset_timings,
-                                     span, timings, traced)
+from cylon_tpu.utils.tracing import (report, reset_timings, scope, span,
+                                     timings, traced)
 
 
 def pow2_bucket(n: int, minimum: int = 1) -> int:
@@ -21,5 +21,5 @@ def pow2_bucket(n: int, minimum: int = 1) -> int:
 __all__ = [
     "disable_logging", "get_logger", "init_logging", "log_level",
     "pow2_bucket",
-    "profile_to", "report", "reset_timings", "span", "timings", "traced",
+    "report", "reset_timings", "scope", "span", "timings", "traced",
 ]

@@ -1,25 +1,36 @@
-"""Op tracing: wall-clock spans + JAX profiler hooks.
+"""Op tracing: wall-clock spans on the host, name scopes on the device.
 
 The reference has no tracer — it inlines ``std::chrono`` timing and
 glog INFO lines at op boundaries (shuffle timings ``table.cpp:167-177``;
 bench binaries log ``j_t``/``w_t`` per rank,
 ``cpp/src/examples/bench/table_join_dist_test.cpp:38-56``). The rebuild
-formalises that: every public op runs under a :func:`span`, spans
-accumulate into the process telemetry registry
-(:mod:`cylon_tpu.telemetry` — one registry for spans, watchdog section
-timings and engine counters, exportable as JSONL/Prometheus), and the
-same spans emit ``jax.profiler.TraceAnnotation`` so they line up with
-XLA device traces in xprof/tensorboard (:func:`profile_to`).
+formalises that in two halves:
 
-:func:`span`/:func:`profile_to`/:func:`timings` are kept as thin
-wrappers over the registry so existing callers (and their tests) are
-untouched; :class:`SpanStat` remains the aggregate view type. When the
-flight recorder is armed (``CYLON_TPU_TRACE`` —
-:mod:`cylon_tpu.telemetry.trace`), every span additionally emits
-begin/end events with parent nesting into the trace buffer, so the
-same instrumentation feeds the histogram aggregates AND the
-Chrome-trace timelines; with the recorder off, the only addition over
-the pre-recorder span is one env read.
+* **Spans** (:func:`span`, :func:`traced`) time host regions. They
+  accumulate into the process telemetry registry
+  (:mod:`cylon_tpu.telemetry` — one registry for spans, watchdog
+  section timings and engine counters, exportable as
+  JSONL/Prometheus), and each also emits a
+  ``jax.profiler.TraceAnnotation``, so a profiler trace shows it on
+  the host timeline beside the device's operations. When the flight
+  recorder is armed (``CYLON_TPU_TRACE`` —
+  :mod:`cylon_tpu.telemetry.trace`), every span additionally emits
+  begin/end events with parent nesting into the trace buffer; with the
+  recorder off, the only addition over the pre-recorder span is one
+  env read.
+* **Scopes** (:func:`scope`) name device work. A compiled query is one
+  XLA program, so no host span can say which operator a device
+  operation belongs to; a scope is a ``jax.named_scope`` from the one
+  vocabulary :data:`SCOPES`, entered while the operator traces. It
+  acts at trace time only: the compiled instructions are unchanged and
+  every operation's metadata carries the scope, which a profiler trace
+  shows as the operation's ``tf_op``
+  (``jit(q1)/jit(filter_table)/cylon.filter/compact/gather``). Scopes
+  are read from the device trace, spans from the host timeline.
+
+:func:`span`/:func:`timings` are kept as thin wrappers over the
+registry so existing callers (and their tests) are untouched;
+:class:`SpanStat` remains the aggregate view type.
 
 Caveat that doesn't exist in the reference: JAX dispatch is async, so a
 span around a jitted call measures *host orchestration* unless
@@ -36,6 +47,21 @@ from cylon_tpu.utils.logging import get_logger
 
 #: the telemetry series spans record into (label ``name`` = span name)
 SPAN_METRIC = "tracing.span_seconds"
+
+#: The operators whose device work carries a ``cylon.<operator>``
+#: scope, each with the stages its body splits into. A nested operator
+#: (the sort inside a join) belongs to its outermost ``cylon.`` scope.
+SCOPES = {
+    "filter": ("mask", "compact"),
+    "join": ("keys", "sort", "match", "order", "gather"),
+    "groupby": ("sort", "permute", "reduce"),
+    "sort": ("keys", "permute"),
+    "take": (),
+    "project": (),
+    "aggregate": (),
+    "plan": (),
+    "exchange": ("partition", "pack", "all-to-all", "unpack"),
+}
 
 
 @dataclass
@@ -95,6 +121,20 @@ def span(name: str, sync=None, cat: "str | None" = None, **targs):
                 get_logger().debug("%s: %.3f ms", name, dt * 1e3)
     finally:
         _trace.end(tok)
+
+
+def scope(operator: str, stage: "str | None" = None):
+    """The ``jax.named_scope`` of ``operator`` (``cylon.<operator>``)
+    or, entered inside it, of one of its stages (the stage's bare name,
+    so the trace reads ``cylon.join/match``). Both come from
+    :data:`SCOPES`; anything else raises ``ValueError``."""
+    import jax
+
+    stages = SCOPES.get(operator)
+    if stages is None or (stage is not None and stage not in stages):
+        raise ValueError(f"no scope {operator!r}/{stage!r} in "
+                         "tracing.SCOPES")
+    return jax.named_scope(f"cylon.{operator}" if stage is None else stage)
 
 
 def traced(name: str | None = None):
@@ -176,17 +216,3 @@ def report(tenant: "str | None" = None) -> str:
             f"{(p50 or 0.0) * 1e3:>8.3f}  {(p99 or 0.0) * 1e3:>8.3f}  "
             f"{s.max_s * 1e3:>8.3f}")
     return "\n".join(lines)
-
-
-@contextlib.contextmanager
-def profile_to(logdir: str):
-    """Capture a JAX/XLA device profile (xprof format) for the enclosed
-    region — the deep-dive tool the reference lacks; view with
-    tensorboard or xprof."""
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
