@@ -8,13 +8,15 @@ then templated ``aggregate<op>`` walks rows updating per-group state
 ``pipeline_groupby.cpp``.
 
 TPU-first: group ids come from one lexsort (collision-free, no hash
-map). On TPU every decomposable aggregate then fuses into ONE
+map). The aggregates are planned as CHANNELS, each distinct per-group
+reduction once (:func:`_channel_plan`). On TPU up to
+:data:`SEGSCAN_MAX_ROWS` rows every channel then fuses into ONE
 segmented scan + ONE compaction sort (``kernels.segmented_totals``) —
 XLA's segment-op lowering is the slowest primitive on the platform
-(~97 ms/aggregate at 1M rows x 600k f64 segments on v5e vs ~2-4 ms
-fused here); CPU meshes keep the segment ops, which win there. The
-"pipeline groupby" specialisation is unnecessary — sorted input just
-makes the same lexsort cheap.
+(~97 ms/channel at 1M rows x 600k f64 segments on v5e vs ~2-4 ms
+fused here); CPU meshes, and the TPU above that size, run one segment
+op per channel. The "pipeline groupby" specialisation is unnecessary —
+sorted input just makes the same lexsort cheap.
 
 Group order in the output is key-sorted (== pandas ``sort=True``).
 """
@@ -45,25 +47,20 @@ AGG_OPS = ("sum", "count", "size", "min", "max", "mean", "var", "std",
 _EAGER_SCALE_MEMO: dict = {}
 
 
-def _segment_sum(vals, gid, num_segments: int):
-    """XLA segment sum over GROUP-SORTED gid (monotone), hence the
-    sorted flag. This is the CPU-mesh path only: on TPU every group
-    reduction rides ``kernels.segmented_totals`` (see
-    :func:`_use_segscan`). An MXU one-hot Pallas segment-sum kernel
-    covered the (f32, <=8192 groups) corner through r3; retired —
-    unreachable once segmented_totals owned the whole TPU path, and
-    measured behind it at every group count (VERDICT r3 weak #6)."""
-    return jax.ops.segment_sum(vals, gid, num_segments=num_segments,
-                               indices_are_sorted=True)
-
-
 #: row-count ceiling for the segmented-scan aggregation path on TPU.
 #: The crossover runs BOTH ways: at 1M rows / 600k groups the scan
 #: beats the x64-emulated segment lowering ~16x (r3 measurement,
 #: ~97 ms -> ~6 ms), but ``lax.associative_scan`` collapses at larger
 #: shapes — on v5e at 6M rows even ONE segmented f64 channel runs for
-#: MINUTES, while 8 sorted segment_sum channels at 6M/400k segments
-#: finish in under a second. ``CYLON_TPU_SEGSCAN_MAX`` overrides.
+#: MINUTES, and its compile grows faster than the rows: for a
+#: described v5e the scan of TPC-H Q1's nine channels alone compiles in
+#: 191 s at 1M rows, 683 s at 2M, and not within 700 s at 6M (the
+#: compaction sort at 6M: 50 s). The segment ops are not cheap there
+#: either: a v5e trace of SF1 Q1 (6M rows, 4 groups) reads ~510 ms per
+#: 64-bit segment-sum channel (an x64-emulated (f32, f32) scatter) and
+#: ~50 ms per 32-bit one, which is why the channel plan merges
+#: duplicates and counts in int32.
+#: ``CYLON_TPU_SEGSCAN_MAX`` overrides.
 SEGSCAN_MAX_ROWS = 2_000_000
 
 
@@ -226,9 +223,10 @@ def _aggregate_segments(stab: Table, table: Table, by, specs, gid_s,
                         num_groups, out_cap: int, quantile, orig_idx,
                         iota) -> dict:
     """The segment-op path (CPU meshes, and the TPU above
-    :data:`SEGSCAN_MAX_ROWS`): one XLA segment reduction per aggregate
-    over the group-sorted layout (``stab``/``gid_s``); ``iota`` is the
-    original table's row index."""
+    :data:`SEGSCAN_MAX_ROWS`): one XLA segment reduction per distinct
+    CHANNEL of :func:`_channel_plan` (not per aggregate) over the
+    group-sorted layout (``stab``/``gid_s``); ``iota`` is the original
+    table's row index."""
     cap = table.capacity
     big = jnp.int32(cap)
     first_pos = jax.ops.segment_min(jnp.where(gid_s < big, iota, big),
@@ -244,25 +242,89 @@ def _aggregate_segments(stab: Table, table: Table, by, specs, gid_s,
     for n in by:
         out[n] = keytab.column(n)
 
-    for src, op, name in specs:
-        out[name] = _aggregate_column(stab, src, op, gid_s, num_groups,
-                                      out_cap, quantile)
+    channels, plans = _channel_plan(stab, specs, gid_s, num_groups,
+                                    out_cap, quantile, _nunique, _quantile)
+    outputs = [_reduce_segments(kind, val, gid_s, out_cap)
+               for kind, val in channels]
+    for name, post in plans:
+        out[name] = post(outputs)
     return out
+
+
+def _reduce_segments(kind, val, gid_s, out_cap: int) -> tuple:
+    """Reduce one channel (see :func:`kernels.segmented_totals` for the
+    kinds) with ONE XLA segment op over GROUP-SORTED ``gid_s``
+    (monotone, padding rows last with id == capacity), hence the sorted
+    flag. Missing values are masked out of the VALUES (zero /
+    sentinel), never the indices: sentinel ids would break
+    monotonicity. first/last are a positional segment min/max over row
+    indices (the stable group sort kept original row order within each
+    group, so that is pandas first/last), then an [out_cap]-row gather."""
+    seg = dict(indices_are_sorted=True, num_segments=out_cap)
+    if kind == "sum":
+        return (jax.ops.segment_sum(val, gid_s, **seg),)
+    if kind == "min":
+        return (jax.ops.segment_min(val, gid_s, **seg),)
+    if kind == "max":
+        return (jax.ops.segment_max(val, gid_s, **seg),)
+    data, has = val
+    cap = gid_s.shape[0]
+    iota = jnp.arange(cap, dtype=jnp.int32)
+    if kind == "first":
+        idx = jax.ops.segment_min(jnp.where(has, iota, cap), gid_s, **seg)
+    else:
+        idx = jax.ops.segment_max(jnp.where(has, iota, -1), gid_s, **seg)
+    found = (idx >= 0) & (idx < cap)
+    return data[jnp.clip(idx, 0, max(cap - 1, 0))], found
 
 
 def _aggregate_scan(stab: Table, orig_table: Table, by, specs, gid_s,
                     num_groups, out_cap: int, q: float, orig_idx) -> dict:
-    """TPU fast path: ALL decomposable aggregates fuse into ONE
+    """TPU fast path: ALL channels of :func:`_channel_plan` fuse into ONE
     segmented scan + ONE compaction sort (``kernels.segmented_totals``)
-    — replacing one XLA segment op per aggregate (each ~97 ms at 1M
+    — replacing one XLA segment op per channel (each ~97 ms at 1M
     rows / 600k f64 segments on v5e) with an ~11 ms fused pass.
     nunique/median/quantile keep their own (gid, value) sort but their
     per-group reductions ride the same scan+compact machinery.
     ``stab``/``gid_s`` are the group-sorted layout."""
+    channels, plans = _channel_plan(stab, specs, gid_s, num_groups,
+                                    out_cap, q, _nunique_scan,
+                                    _quantile_scan)
+    outputs, extra = kernels.segmented_totals(gid_s, out_cap, channels,
+                                              extras=[orig_idx])
+    out = {}
+    leader = extra[0]   # original row index of each group's last row
+    keytab = take_columns(orig_table, leader, num_groups, names=list(by))
+    for n in by:
+        out[n] = keytab.column(n)
+    for name, post in plans:
+        out[name] = post(outputs)
+    return out
+
+
+def _channel_plan(stab: Table, specs, gid_s, num_groups, out_cap: int,
+                  q: float, nunique, quantile) -> tuple[list, list]:
+    """Pass 1 of both reduce paths: register each distinct per-group
+    reduction ONCE as a channel, and each aggregate as a ``post``
+    function over the channels' outputs.
+
+    A channel is ``(kind, value)`` as :func:`kernels.segmented_totals`
+    takes it. Channels are keyed so that equal work merges: a value sum
+    is ``("sum", src, accumulator dtype)`` for sum, mean, var and std
+    alike (an f64 column asked for its sum and its mean is summed once;
+    an integer column's i64 sum and f64 mean stay two), sums of squares
+    likewise; every count is int32 (a count is at most the capacity,
+    below 2^31, so exact) and cast after the reduction, and columns
+    without null flags share the one valid-row count with ``size``.
+    ``nunique(stab, src, gid_s, gvalid, out_cap)`` and ``quantile(...,
+    q)`` build those aggregates from their own (gid, value) sorts.
+
+    Returns ``(channels, plans)``: ``plans`` is ``[(name, post)]`` with
+    ``post(outputs) -> Column``, ``outputs[i]`` the tuple channel ``i``
+    reduces to, [out_cap] rows aligned to dense group id."""
     cap = stab.capacity
     vmask = kernels.valid_mask(cap, stab.nrows)
-    gslot = jnp.arange(out_cap, dtype=jnp.int32)
-    gvalid = gslot < num_groups
+    gvalid = jnp.arange(out_cap, dtype=jnp.int32) < num_groups
 
     channels: list = []
     index_of: dict = {}
@@ -273,49 +335,57 @@ def _aggregate_scan(stab: Table, orig_table: Table, by, specs, gid_s,
             channels.append((kind, val))
         return index_of[key]
 
-    ok_cache: dict = {}
+    ok_cache: dict = {}   # src -> (count key, valid non-null rows)
 
     def ok_of(src):
         if src not in ok_cache:
-            c = stab.column(src)
-            nulls = _null_flags(c)
-            ok_cache[src] = vmask if nulls is None \
-                else (vmask & (nulls == 0))
+            nulls = _null_flags(stab.column(src))
+            ok_cache[src] = ((None, vmask) if nulls is None
+                             else (src, vmask & (nulls == 0)))
         return ok_cache[src]
 
     def masked(src, fill, dtype=None):
         c = stab.column(src)
-        ok_b = ok_of(src).reshape((cap,) + (1,) * (c.data.ndim - 1))
+        ok_b = ok_of(src)[1].reshape((cap,) + (1,) * (c.data.ndim - 1))
         data = c.data if dtype is None else c.data.astype(dtype)
         return jnp.where(ok_b, data, jnp.asarray(fill, data.dtype))
 
     def count_chan(src):
-        return chan(("count", src), "sum", ok_of(src).astype(jnp.int32))
+        key, ok = ok_of(src)
+        return chan(("count", key), "sum", ok.astype(jnp.int32))
 
-    # ---- pass 1: register channels ----------------------------------
-    plans = []   # (name, post(outputs) -> Column)
+    def sum_chan(src, acc):
+        acc = jnp.dtype(acc)
+        return chan(("sum", src, acc), "sum", masked(src, 0, acc))
+
+    def sumsq_chan(src, f):
+        v = masked(src, 0, f)
+        return chan(("sumsq", src, jnp.dtype(f)), "sum", v * v)
+
+    def float_of(c):
+        return jnp.float64 if c.data.dtype.itemsize >= 4 else jnp.float32
+
+    plans = []
     for src, op, name in specs:
         c = stab.column(src)
-        if op == "size":
-            i = chan(("size",), "sum", vmask.astype(jnp.int32))
-            plans.append((name, lambda o, i=i: Column(
-                o[i][0].astype(jnp.int64), None, dtypes.int64)))
-        elif op == "count":
-            i = count_chan(src)
+        if op in ("size", "count"):
+            i = (count_chan(src) if op == "count" else
+                 chan(("count", None), "sum", vmask.astype(jnp.int32)))
             plans.append((name, lambda o, i=i: Column(
                 o[i][0].astype(jnp.int64), None, dtypes.int64)))
         elif op == "sum":
             acc = kernels._acc_dtype(c.data.dtype)
-            i = chan(("sum", src), "sum", masked(src, 0, acc))
+            i = sum_chan(src, acc)
             plans.append((name, lambda o, i=i, acc=acc: Column(
                 o[i][0], None, dtypes.from_numpy_dtype(acc))))
         elif op == "sumsq":
-            f = jnp.float64 if c.data.dtype.itemsize >= 4 else jnp.float32
-            v = masked(src, 0, f)
-            i = chan(("sumsq", src), "sum", v * v)
+            f = float_of(c)
+            i = sumsq_chan(src, f)
             plans.append((name, lambda o, i=i, f=f: Column(
                 o[i][0], None, dtypes.from_numpy_dtype(f))))
         elif op in ("min", "max"):
+            # dictionary codes are order-preserving, so min/max of codes
+            # is correct for string columns too
             sent = (dtypes.sentinel_high(c.data.dtype) if op == "min"
                     else dtypes.sentinel_low(c.data.dtype))
             i = chan((op, src), op, masked(src, sent))
@@ -323,23 +393,22 @@ def _aggregate_scan(stab: Table, orig_table: Table, by, specs, gid_s,
             plans.append((name, lambda o, i=i, ic=ic, c=c: Column(
                 o[i][0], gvalid & (o[ic][0] > 0), c.dtype, c.dictionary)))
         elif op in ("mean", "var", "std"):
-            f = jnp.float64 if c.data.dtype.itemsize >= 4 else jnp.float32
-            isum = chan(("fsum", src, f), "sum", masked(src, 0, f))
+            f = float_of(c)
+            isum = sum_chan(src, f)
             ic = count_chan(src)
-            if op != "mean":
-                v = masked(src, 0, f)
-                isq = chan(("sumsq", src), "sum", v * v)
+            isq = None if op == "mean" else sumsq_chan(src, f)
 
-            def post(o, isum=isum, ic=ic, op=op, f=f,
-                     isq=None if op == "mean" else isq):
+            def post(o, isum=isum, ic=ic, isq=isq, op=op, f=f):
                 s = o[isum][0]
                 n = o[ic][0].astype(f)
+                # counts are per group; broadcast over trailing dims
                 n_b = n.reshape(n.shape + (1,) * (s.ndim - 1))
                 if op == "mean":
                     return Column(s / jnp.maximum(n_b, 1.0),
                                   gvalid & (n > 0),
                                   dtypes.from_numpy_dtype(f))
                 sq = o[isq][0]
+                # ddof=1 (pandas default)
                 var = ((sq - s * s / jnp.maximum(n_b, 1.0))
                        / jnp.maximum(n_b - 1.0, 1.0))
                 var = jnp.maximum(var, 0.0)
@@ -349,33 +418,19 @@ def _aggregate_scan(stab: Table, orig_table: Table, by, specs, gid_s,
 
             plans.append((name, post))
         elif op in ("first", "last"):
-            i = chan((op, src), op, (c.data, ok_of(src)))
+            i = chan((op, src), op, (c.data, ok_of(src)[1]))
             plans.append((name, lambda o, i=i, c=c: Column(
                 o[i][0], gvalid & o[i][1], c.dtype, c.dictionary)))
         elif op == "nunique":
             plans.append((name, lambda _o, a=(stab, src, gid_s, gvalid,
-                                              out_cap):
-                          _nunique_scan(*a)))
+                                              out_cap): nunique(*a)))
         elif op in ("median", "quantile"):
             qq = 0.5 if op == "median" else q
             plans.append((name, lambda _o, a=(stab, src, gid_s, gvalid,
-                                              out_cap, qq):
-                          _quantile_scan(*a)))
+                                              out_cap, qq): quantile(*a)))
         else:  # pragma: no cover — specs pre-validated
             raise InvalidArgument(f"unhandled aggregation {op!r}")
-
-    # ---- pass 2: one fused scan + compaction ------------------------
-    outputs, extra = kernels.segmented_totals(gid_s, out_cap, channels,
-                                              extras=[orig_idx])
-    out = {}
-    leader = extra[0]   # original row index of each group's last row
-    keytab = take_columns(orig_table, leader, num_groups, names=list(by))
-    for n in by:
-        out[n] = keytab.column(n)
-    for name, post in plans:
-        res = post(outputs)
-        out[name] = res
-    return out
+    return channels, plans
 
 
 def _nunique_scan(stab, src, gid_s, gvalid, out_cap: int) -> Column:
@@ -436,111 +491,16 @@ def _quantile_scan(stab, src, gid_s, gvalid, out_cap: int,
     return Column(data, gvalid & (n > 0), dtypes.from_numpy_dtype(f))
 
 
-def _aggregate_column(table: Table, src: str, op: str, gid, num_groups,
-                      out_cap: int, q: float) -> Column:
-    """Reduce one column. ``table``/``gid`` are in GROUP-SORTED layout
-    (monotone segment ids, padding rows last with id == capacity), so
-    every segment reduction runs with ``indices_are_sorted=True``.
-    Missing values are masked out of the VALUES (zero / sentinel), never
-    the indices — sentinel ids would break monotonicity."""
-    c = table.column(src)
-    cap = table.capacity
-    vmask = kernels.valid_mask(cap, table.nrows)
-    nulls = _null_flags(c)
-    value_ok = vmask if nulls is None else (vmask & (nulls == 0))
-    # broadcast the row mask over trailing dims of multi-dim columns
-    ok_b = value_ok.reshape((cap,) + (1,) * (c.data.ndim - 1))
-    gslot = jnp.arange(out_cap, dtype=jnp.int32)
-    gvalid = gslot < num_groups
-
-    def seg_sum(vals):
-        return jax.ops.segment_sum(vals, gid, num_segments=out_cap,
-                                   indices_are_sorted=True)
-
-    if op == "size":
-        # padding contributes zeros (value-masked — robust even when a
-        # caller passes out_capacity > table capacity). Accumulate in
-        # int32 (counts <= capacity < 2^31): 64-bit integer segment
-        # reductions run ~5x slower under the TPU x64 emulation.
-        return Column(seg_sum(vmask.astype(jnp.int32)).astype(jnp.int64),
-                      None, dtypes.int64)
-    if op == "count":
-        return Column(
-            seg_sum(value_ok.astype(jnp.int32)).astype(jnp.int64),
-            None, dtypes.int64)
-    if op == "sum":
-        acc = kernels._acc_dtype(c.data.dtype)
-        vals = jnp.where(ok_b, c.data, jnp.zeros((), c.data.dtype))
-        data = _segment_sum(vals.astype(acc), gid, out_cap)
-        return Column(data, None, dtypes.from_numpy_dtype(acc))
-    if op == "sumsq":
-        f = jnp.float64 if c.data.dtype.itemsize >= 4 else jnp.float32
-        vals = jnp.where(ok_b, c.data.astype(f), 0.0)
-        return Column(seg_sum(vals * vals), None,
-                      dtypes.from_numpy_dtype(f))
-    if op in ("min", "max"):
-        # dictionary codes are order-preserving, so min/max of codes is
-        # correct for string columns too
-        sent = (dtypes.sentinel_high(c.data.dtype) if op == "min"
-                else dtypes.sentinel_low(c.data.dtype))
-        vals = jnp.where(ok_b, c.data, jnp.asarray(sent, c.data.dtype))
-        red = jax.ops.segment_min if op == "min" else jax.ops.segment_max
-        data = red(vals, gid, num_segments=out_cap,
-                   indices_are_sorted=True)
-        cnt = seg_sum(value_ok.astype(jnp.int32))
-        return Column(data, gvalid & (cnt > 0), c.dtype, c.dictionary)
-    if op in ("mean", "var", "std"):
-        f = jnp.float64 if c.data.dtype.itemsize >= 4 else jnp.float32
-        vals = jnp.where(ok_b, c.data.astype(f), 0.0)
-        s = seg_sum(vals)
-        n = seg_sum(value_ok.astype(f))
-        # counts are per group; broadcast over trailing dims of the sums
-        n_b = n.reshape(n.shape + (1,) * (s.ndim - 1))
-        if op == "mean":
-            data = s / jnp.maximum(n_b, 1.0)
-            return Column(data, gvalid & (n > 0), dtypes.from_numpy_dtype(f))
-        sq = seg_sum(vals * vals)
-        # ddof=1 (pandas default)
-        var = ((sq - s * s / jnp.maximum(n_b, 1.0))
-               / jnp.maximum(n_b - 1.0, 1.0))
-        var = jnp.maximum(var, 0.0)
-        data = jnp.sqrt(var) if op == "std" else var
-        return Column(data, gvalid & (n > 1), dtypes.from_numpy_dtype(f))
-    if op in ("first", "last"):
-        # stable group-sort preserved original row order within each
-        # group, so positional min/max == pandas first/last
-        iota = jnp.arange(cap, dtype=jnp.int32)
-        if op == "first":
-            idx = jax.ops.segment_min(jnp.where(value_ok, iota, cap), gid,
-                                      num_segments=out_cap,
-                                      indices_are_sorted=True)
-        else:
-            idx = jax.ops.segment_max(jnp.where(value_ok, iota, -1), gid,
-                                      num_segments=out_cap,
-                                      indices_are_sorted=True)
-        has = (idx >= 0) & (idx < cap)
-        idx = jnp.clip(idx, 0, max(cap - 1, 0))
-        data = c.data[idx]
-        return Column(data, gvalid & has, c.dtype, c.dictionary)
-    # nunique/median re-sort by (gid, value) internally; they take the
-    # sentinel-id form (monotonicity not required there)
-    gid_v = jnp.where(value_ok, gid, out_cap)
-    if op == "nunique":
-        return _nunique(c, gid_v, gvalid, out_cap)
-    if op in ("median", "quantile"):
-        qq = 0.5 if op == "median" else q
-        return _quantile(c, gid_v, gvalid, out_cap, qq)
-    raise InvalidArgument(f"unhandled aggregation {op!r}")
-
-
-def _nunique(c: Column, gid_v, gvalid, out_cap: int) -> Column:
+def _nunique(stab: Table, src: str, gid_s, gvalid, out_cap: int) -> Column:
     """Distinct non-null values per group: sort rows by (gid, value) and
     count run boundaries per group (parity: NUNIQUE kernel,
     ``aggregate_kernels.hpp``). The (gid, value-order-key) pairs ARE the
     sort operands — no permutation, no gather; order-key equality ==
     value equality (canonical NaN / -0.0)."""
+    c = stab.column(src)
     cap = c.data.shape[0]
-    g_s, v_s = jax.lax.sort((gid_v, kernels.order_key(c.data)),
+    g_s, v_s = jax.lax.sort((_gid_of_values(stab, src, gid_s, out_cap),
+                             kernels.order_key(c.data)),
                             num_keys=2, is_stable=False)
     iota = jnp.arange(cap, dtype=jnp.int32)
     new_grp = g_s != jnp.roll(g_s, 1)
@@ -554,16 +514,18 @@ def _nunique(c: Column, gid_v, gvalid, out_cap: int) -> Column:
     return Column(data.astype(jnp.int64), None, dtypes.int64)
 
 
-def _quantile(c: Column, gid_v, gvalid, out_cap: int, q: float) -> Column:
+def _quantile(stab: Table, src: str, gid_s, gvalid, out_cap: int,
+              q: float) -> Column:
     """Per-group linear-interpolated quantile over non-null values
     (parity: QUANTILE kernel). Sort by (gid, value), then index each
     group's run at q*(n-1)."""
+    c = stab.column(src)
     cap = c.data.shape[0]
     f = jnp.float64 if c.data.dtype.itemsize >= 4 else jnp.float32
     # values ride the (gid, value-key) sort as payload — no perm/gather
     g_s, _, v_raw = jax.lax.sort(
-        (gid_v, kernels.order_key(c.data), c.data), num_keys=2,
-        is_stable=False)
+        (_gid_of_values(stab, src, gid_s, out_cap),
+         kernels.order_key(c.data), c.data), num_keys=2, is_stable=False)
     v_s = v_raw.astype(f)
     n = jax.ops.segment_sum(jnp.ones(cap, jnp.int32),
                             jnp.where(g_s < out_cap, g_s, out_cap),
@@ -578,6 +540,17 @@ def _quantile(c: Column, gid_v, gvalid, out_cap: int, q: float) -> Column:
     take_hi = jnp.clip(start + hi, 0, max(cap - 1, 0))
     data = v_s[take_lo] * (1 - w) + v_s[take_hi] * w
     return Column(data, gvalid & (n > 0), dtypes.from_numpy_dtype(f))
+
+
+def _gid_of_values(stab: Table, src: str, gid_s, out_cap: int):
+    """Group ids with every missing value's row sent to the sentinel
+    ``out_cap`` (the re-sorts of nunique/quantile need no monotone ids)."""
+    c = stab.column(src)
+    ok = kernels.valid_mask(stab.capacity, stab.nrows)
+    nulls = _null_flags(c)
+    if nulls is not None:
+        ok = ok & (nulls == 0)
+    return jnp.where(ok, gid_s, out_cap)
 
 
 #: value words the TPU group sort may carry: one 64-bit column. Each

@@ -16,7 +16,8 @@ retired once ``kernels.segmented_totals`` — the segmented-scan +
 compaction-sort path — took over ALL TPU group reductions: its gate
 (f32, 1-D, <=8192 groups) had become unreachable on every default
 path, and measured v5e numbers showed segmented_totals ahead at both
-small and large group counts. See ``ops/groupby.py:_segment_sum``.)
+small and large group counts. See
+``ops/groupby.py:_reduce_segments``.)
 
 All kernels run in ``interpret`` mode off-TPU, so the exact code path
 unit-tested on the CPU mesh (``tests/conftest.py``) is what compiles on

@@ -195,3 +195,45 @@ def test_scope_vocabulary():
     for bad in (("joins",), ("join", "probe"), ("take", "gather")):
         with pytest.raises(ValueError, match="SCOPES"):
             tracing.scope(*bad)
+
+
+_SCATTER = re.compile(r'"stablehlo\.scatter"')
+_REDUCER = re.compile(r'stablehlo\.(add|minimum|maximum)\b')
+
+
+def scatter_set(text):
+    """``Counter((reduction, element type))`` of every scatter in a
+    lowered module: the reduction is read from the scatter's combiner
+    region, the element type from the region's first argument."""
+    lines = text.splitlines()
+    found = collections.Counter()
+    for i, line in enumerate(lines):
+        if not _SCATTER.search(line):
+            continue
+        j = next(k for k in range(i, len(lines)) if "^bb0" in lines[k])
+        ty = re.search(r"tensor<(\w+)>", lines[j]).group(1)
+        k = next(k for k in range(j, len(lines))
+                 if "stablehlo.return" in lines[k])
+        red = next(m.group(1) for m in map(_REDUCER.search, lines[j:k])
+                   if m)
+        found[red, ty] += 1
+    return found
+
+
+@pytest.mark.parametrize("q", ["q1", "q3", "q5"])
+def test_groupby_reduces_each_channel_once(q, frames):
+    """The segment path (the CPU's, and the TPU's above
+    ``SEGSCAN_MAX_ROWS``) runs one scatter per distinct channel. Q1
+    sums l_extendedprice once for its sum and its mean, l_quantity in
+    i64 for the sum and in f64 for the mean, and counts in int32: 5 f64
+    and 1 i64 scatter-adds, a few i32 counts, and the i32 scatter-min of
+    the group leaders. Q3 and Q5 sum one f64 column each."""
+    found = scatter_set(_lowered(q, frames, "cpu"))
+    adds = {ty: n for (red, ty), n in found.items() if red == "add"}
+    if q == "q1":
+        assert adds.pop("f64") == 5 and adds.pop("i64") == 1, found
+        assert 1 <= adds.pop("i32") <= 3, found
+        assert found["minimum", "i32"] == 1, found
+    else:
+        assert adds.pop("f64") == 1, found
+    assert not adds, found
