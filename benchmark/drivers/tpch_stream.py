@@ -21,6 +21,9 @@ import traceback
 
 import numpy as np
 
+#: the configuration of a CPU rehearsal
+TINY = {"scale_factor": 0.01}
+
 
 def _query(name):
     import importlib
@@ -179,3 +182,42 @@ def control(config, traffic, seed):
                                        wants, config["rtol"])
     return attempted, failed, {"max_rel_err": (worst, config["rtol"]),
                                "wrong_answers": (failed, 0)}
+
+
+# ---------------------------------------------------------------- faults
+# Each breaks the engine's timed path; a rehearsal with it has to come
+# out not correct.
+
+def _altered():
+    """An answer altered where it is produced: a result frame's first
+    float column is off by one part in a million."""
+    from cylon_tpu import frame
+
+    to_pandas = frame.DataFrame.to_pandas
+
+    def wrong_pandas(self, *a, **kw):
+        df = to_pandas(self, *a, **kw)
+        f = [c for c in df.columns if df[c].dtype.kind == "f"]
+        if f:
+            df[f[0]] = df[f[0]] * (1 + 1e-6)
+        return df
+
+    frame.DataFrame.to_pandas = wrong_pandas
+
+
+def _half():
+    """Half of the rows left out: every table loses its second half."""
+    from cylon_tpu import tpch
+
+    ingest = tpch.ingest
+
+    def half_ingest(data):
+        return ingest({t: ({c: v[:max(len(v) // 2, 1)]
+                            for c, v in cols.items()}
+                           if isinstance(cols, dict) else cols)
+                       for t, cols in data.items()})
+
+    tpch.ingest = half_ingest
+
+
+FAULTS = {"altered": _altered, "half": _half}

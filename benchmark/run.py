@@ -131,7 +131,9 @@ def chips(n, require_tpu=True):
 
 class CompileWatch:
     """Counts XLA compiles and persistent-cache loads as JAX reports
-    them, so a compile inside the window shows."""
+    them, so a compile inside the window shows. JAX times a program
+    loaded from the cache as a compile too; :meth:`counts` leaves those
+    out of the compiles."""
 
     def __init__(self):
         import jax.monitoring as mon
@@ -151,22 +153,29 @@ class CompileWatch:
         mon.register_event_listener(on_event)
 
     def counts(self):
-        return self.compiles, self.cache_loads
+        return self.compiles - self.cache_loads, self.cache_loads
 
 
 class Reading:
     """What a per-layer metric reader is given: the program's counters
     and spans over the traced window (``delta``, a telemetry delta), the
-    reduced device trace (``trace``, a ``TraceSummary``), the units of
-    work the window completed (``units``: whole passes), the least
-    HBM bytes one unit must move (``least_bytes``), the chips used and
-    the device's published ``peaks``."""
+    reduced device trace (``trace``, a ``TraceSummary``, its operator
+    scopes in ``trace.scopes``), the units of work the window completed
+    (``units``: whole passes), the least HBM bytes one unit must move
+    (``least_bytes``) and the least bytes one chip must send to other
+    chips per unit (``least_ici_bytes``), each None where the driver
+    has no such function, the programs loaded from the persistent
+    compilation cache in the window (``cache_loads``), the chips used
+    and the device's published ``peaks``."""
 
-    def __init__(self, delta, trace, units, least_bytes, n_chips, peaks):
+    def __init__(self, delta, trace, units, least_bytes, n_chips, peaks,
+                 least_ici_bytes=None, cache_loads=None):
         self.delta = delta
         self.trace = trace
         self.units = units
         self.least_bytes = least_bytes
+        self.least_ici_bytes = least_ici_bytes
+        self.cache_loads = cache_loads
         self.chips = n_chips
         self.peaks = peaks
 
@@ -205,6 +214,7 @@ def measure(driver, state, seconds, trace, profile=True):
     from cylon_tpu import telemetry
 
     import trace_reduce
+    import trace_scopes
 
     if not (trace and profile):
         before = telemetry.snapshot()
@@ -227,7 +237,9 @@ def measure(driver, state, seconds, trace, profile=True):
         t0 = time.perf_counter()
         summary = trace_reduce.reduce_file(trace_reduce.find_trace(logdir),
                                            span_names=spans)
-        log(f"trace reduced in {time.perf_counter() - t0:.3f} s")
+        log(f"trace reduced in {time.perf_counter() - t0:.3f} s; device "
+            f"time by operator scope:\n"
+            + trace_scopes.scope_table(summary.scopes, passes=win["units"]))
     return win, delta, summary
 
 
@@ -270,7 +282,9 @@ def run(workload, seed, seconds, trace, require_tpu=True, overrides=None):
         f"the compilation cache")
     stats = [d.memory_stats() or {} for d in devices]
     peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
-    least_bytes = driver.least_bytes(state) if trace else None
+    least = {name: getattr(driver, name)(state)
+             if trace and hasattr(driver, name) else None
+             for name in ("least_bytes", "least_ici_bytes")}
 
     attempted, failed, checks = driver.check(state, win)
     correct = failed == 0 and attempted > 0 and all(
@@ -278,8 +292,9 @@ def run(workload, seed, seconds, trace, require_tpu=True, overrides=None):
 
     if trace:
         names = [m["name"] for m in cs["per_layer"]]
-        reading = Reading(delta, summary, win["units"], least_bytes,
-                          len(devices), peaks)
+        reading = Reading(delta, summary, win["units"],
+                          least["least_bytes"], len(devices), peaks,
+                          least["least_ici_bytes"], loaded)
         metrics = read_per_layer(names, reading)
         units = {m["name"]: m["unit"] for m in cs["per_layer"]}
     else:

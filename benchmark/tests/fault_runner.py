@@ -3,12 +3,12 @@ the engine's timed path broken underneath, and print the result line.
 
     python fault_runner.py <workload> <fault> <seed> [<trace>]
 
-Faults (``none`` runs the engine as it is):
-
-* ``altered``: an answer altered where it is produced: a TPC-H result
-  frame's first float column is off by one part in a million.
-* ``half``: half of the rows left out: every table loses its second
-  half.
+The tiny size is the cell's driver's ``TINY`` (configuration keys it
+overrides) and the faults are its ``FAULTS`` (name -> a function that
+breaks the engine); ``none`` runs the engine as it is. The process
+needs as many devices as the cell asks for chips: on the CPU,
+``--xla_force_host_platform_device_count`` in ``XLA_FLAGS`` gives them
+(``test_faults.rehearse`` sets it).
 
 The run skips the harness's look for a chip (``require_tpu=False``);
 everything else is the benchmark's own path.
@@ -22,55 +22,25 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 sys.path[:0] = [BENCH, os.path.dirname(BENCH)]
 
-#: the tiny sizes of a CPU rehearsal, by the configuration's scale key
-TINY = {"scale_factor": 0.01}
 
+def driver_of(workload):
+    """The driver module of a cell, by the names in ``BENCHMARK.json``."""
+    import run
 
-def tiny(config):
-    return {k: v for k, v in TINY.items() if k in config}
-
-
-def _altered():
-    from cylon_tpu import frame
-
-    to_pandas = frame.DataFrame.to_pandas
-
-    def wrong_pandas(self, *a, **kw):
-        df = to_pandas(self, *a, **kw)
-        f = [c for c in df.columns if df[c].dtype.kind == "f"]
-        if f:
-            df[f[0]] = df[f[0]] * (1 + 1e-6)
-        return df
-
-    frame.DataFrame.to_pandas = wrong_pandas
-
-
-def _half():
-    from cylon_tpu import tpch
-
-    ingest = tpch.ingest
-
-    def half_ingest(data):
-        return ingest({t: ({c: v[:max(len(v) // 2, 1)]
-                            for c, v in cols.items()}
-                           if isinstance(cols, dict) else cols)
-                       for t, cols in data.items()})
-
-    tpch.ingest = half_ingest
-
-
-FAULTS = {"none": lambda: None, "altered": _altered, "half": _half}
+    cs = run.cell_spec(run.load_json(run.ROOT, "BENCHMARK.json"), workload)
+    return run.load_module("drivers", cs["traffic"]["driver"])
 
 
 def main(workload, fault, seed, trace=0):
     import run
 
-    cs = run.cell_spec(run.load_json(run.ROOT, "BENCHMARK.json"), workload)
+    driver = driver_of(workload)
     import cylon_tpu  # noqa: F401
 
-    FAULTS[fault]()
+    if fault != "none":
+        driver.FAULTS[fault]()
     out = run.run(workload, seed, 1.0, bool(trace), require_tpu=False,
-                  overrides=tiny(cs["config"]))
+                  overrides=driver.TINY)
     print(json.dumps(out), flush=True)
 
 

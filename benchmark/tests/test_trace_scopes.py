@@ -59,6 +59,14 @@ def test_recorded_scoped_query():
     assert unscoped < 0.05 * total
 
 
+def test_summary_carries_the_scopes():
+    """``reduce_file`` hands the readers the same split as
+    ``reduce_scopes``."""
+    summary = trace_reduce.reduce_file(SCOPED)
+    assert summary.scopes == trace_scopes.reduce_scopes(SCOPED)
+    assert trace_scopes.scope_seconds(summary.scopes, "cylon.filter") > 0
+
+
 @pytest.mark.parametrize("tf_op,key", [
     ("jit(q1)/jit(filter_table)/cylon.filter/compact/gather:",
      ("cylon.filter", "compact", "gather")),

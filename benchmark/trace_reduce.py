@@ -21,9 +21,12 @@ benchmark reports.
   the innermost
   host event of any kind, as ``"plan.fetch: np.asarray(jax.Array)"``;
   averaged over the devices.
+* ``scopes``: device seconds per operator scope, ``(operator, stage,
+  primitive)``, averaged over the devices (``trace_scopes``; filled by
+  :func:`reduce_file`).
 
-Only ``jax.profiler.ProfileData`` is used, so the reduction needs
-nothing beyond JAX.
+Only ``jax.profiler.ProfileData`` and the standard library are used, so
+the reduction needs nothing beyond JAX.
 """
 
 import dataclasses
@@ -51,6 +54,7 @@ class TraceSummary:
     devices: int
     ops: dict
     idle: dict
+    scopes: dict = dataclasses.field(default_factory=dict)
 
     def op_seconds(self, pattern):
         """Device seconds (per device) of the operations whose category
@@ -197,7 +201,16 @@ def reduce_profile(profile, window=WINDOW, span_names=()):
 
 
 def reduce_file(path, window=WINDOW, span_names=()):
-    """:func:`reduce_profile` of the ``.xplane.pb`` file at ``path``."""
+    """:func:`reduce_profile` of the ``.xplane.pb`` file at ``path``,
+    with its ``scopes`` from ``trace_scopes``."""
     from jax.profiler import ProfileData
 
-    return reduce_profile(ProfileData.from_file(path), window, span_names)
+    import trace_scopes
+
+    with open(path, "rb") as f:
+        data = f.read()
+    profile = ProfileData.from_serialized_xspace(data)
+    summary = reduce_profile(profile, window, span_names)
+    summary.scopes = trace_scopes.scopes_of(
+        profile, trace_scopes.read_tf_ops(data), window)
+    return summary

@@ -230,6 +230,16 @@ def scope_seconds(scopes, operator=None, primitive=None):
                if operator in (None, op) and primitive in (None, prim))
 
 
+def per_unit_ms(reading, operator=None, primitive=None):
+    """A metric reader's value: device milliseconds per unit of work of
+    :func:`scope_seconds` over a reading's traced window, averaged over
+    the chips; None where the window has no trace or no such time."""
+    if reading.trace is None or not reading.units:
+        return None
+    seconds = scope_seconds(reading.trace.scopes, operator, primitive)
+    return seconds / reading.units * 1e3 if seconds else None
+
+
 def scope_table(scopes, n=20, passes=None):
     """The ``n`` largest entries of ``scopes`` as lines of text, each
     with its share of their sum, then each operator's seconds (and
